@@ -12,8 +12,10 @@ import jax.numpy as jnp
 
 from repro.core.w1a8 import (deploy_w1a8_linear, init_w1a8_linear,
                              w1a8_linear_float_ref, w1a8_linear_infer)
+from repro.launch.peaks import V5E, chip_peaks
 
-V5E_FLOPS, V5E_BW = 197e12, 819e9
+V5E_FLOPS = chip_peaks(V5E)["peak_flops_bf16"]
+V5E_BW = chip_peaks(V5E)["hbm_bw"]
 
 
 def _time(fn, *args, iters=5):

@@ -130,8 +130,10 @@ def requant_epilogue(y: jax.Array, out_step: float,
     conv, fused conv+pool, and matmul kernels all apply after Div_current
     and bias. One definition so the three paths cannot drift in rounding.
     """
-    q = round_half_away(y / out_step)
-    return jnp.clip(q, 0, ACT_QMAX).astype(out_dtype)
+    q = jnp.clip(round_half_away(y / out_step), 0, ACT_QMAX)
+    if jnp.issubdtype(out_dtype, jnp.integer):
+        q = q.astype(jnp.int32)        # the TPU has no direct f32 → u8 cast
+    return q.astype(out_dtype)
 
 
 def fold_codes_to_uniform_step(a_u8: jax.Array,

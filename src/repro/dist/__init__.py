@@ -11,5 +11,4 @@ binary PE must survive the mapping to a pod —
                       scales (the W1A8 wire format applied to collectives),
   * ``pipeline``    — GPipe microbatch pipelining over a mesh axis.
 """
-from repro import compat  # noqa: F401  (installs the jax.shard_map shim)
 from repro.dist import collectives, pipeline, sharding  # noqa: F401

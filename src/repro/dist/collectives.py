@@ -24,7 +24,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro import compat  # noqa: F401
 from repro.core.qtensor import QTensor
 from repro.core.quant import round_half_away
 

@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat  # noqa: F401
 from repro.dist.collectives import (permute_quantized,
                                     tree_quantized_allreduce)
 

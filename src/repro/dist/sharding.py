@@ -33,7 +33,6 @@ import re
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat  # noqa: F401
 
 # leaf names of column-parallel projections (shard output dim over model)
 _COL_PARALLEL = ("wq", "wk", "wv", "up", "gate", "in_proj", "x_proj",

@@ -284,7 +284,7 @@ def normalize(op: str, config: Optional[KernelConfig],
         return config
     if passed:
         _warn_legacy_once()
-    defaults = {"interpret": True}
+    defaults = {}
     if op == "conv3x3_pool":
         defaults["out_step"] = 1.0
     defaults.update(passed)

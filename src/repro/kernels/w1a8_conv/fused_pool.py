@@ -23,20 +23,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat  # noqa: F401  (pltpu.CompilerParams on older jax)
 from repro.core.packing import PACK
 from repro.core.quant import requant_epilogue
 from repro.kernels.w1a8_matmul.kernel import _unpack_tile, _xnor_accumulate
 from repro.kernels.w1a8_conv.kernel import _im2col_rows
 
 
-def _pool_epilogue(y, out_step, nconv: int, w_out: int, cout: int, o_ref):
-    # f32 carrier for the 2×2 max; values are exact uint8 codes
-    y = requant_epilogue(y, out_step, jnp.float32)
-    y = y.reshape(nconv, w_out, cout)
-    both = jnp.maximum(y[0::2], y[1::2])                # vertical 2-max
-    pooled = jnp.maximum(both[:, 0::2, :], both[:, 1::2, :])  # horizontal
-    o_ref[0] = pooled.astype(o_ref.dtype)
+def _pool_epilogue(y, out_step, rows: int, w_out: int, o_ref):
+    """Requant + 2×2 max of (2·rows·W, Cout) f32 → ``rows`` pooled rows.
+
+    Each window corner is gathered by a 0/1 selection matmul: the codes are
+    integers ≤ 255, exact in bf16, and every output sums exactly one of
+    them, so the gather is exact and needs no strided slice or reshape."""
+    codes = requant_epilogue(y, out_step, jnp.float32).astype(jnp.bfloat16)
+    shape = (w_out // 2, 2 * w_out)
+    q = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    sels = [(j == dy * w_out + 2 * q + dx).astype(jnp.bfloat16)
+            for dy in range(2) for dx in range(2)]
+    for p in range(rows):
+        pair = codes[2 * p * w_out:(2 * p + 2) * w_out]      # two conv rows
+        pooled = functools.reduce(jnp.maximum, [
+            jnp.dot(sel, pair, preferred_element_type=jnp.float32)
+            for sel in sels])
+        o_ref[0, p] = pooled.astype(jnp.int32).astype(o_ref.dtype)
 
 
 def _kernel(*refs, rows: int, w_out: int, k9p: int, cout: int,
@@ -45,17 +55,17 @@ def _kernel(*refs, rows: int, w_out: int, k9p: int, cout: int,
     line_rows = [r[0, 0] for r in refs[:nconv + 2]]
     wp_ref, m_ref, d_ref, b_ref, o_ref = refs[nconv + 2:]
     signs = _unpack_tile(wp_ref[...], k9p, cout, compute_dtype)
-    cols = _im2col_rows(line_rows, nconv, w_out, k9p, jnp.float32)
+    cols = _im2col_rows(line_rows, nconv, w_out, k9p).astype(jnp.float32)
     am = (cols * m_ref[...].astype(jnp.float32)).astype(compute_dtype)
     y = jnp.dot(am, signs, preferred_element_type=jnp.float32)
     y = (y * d_ref[...].astype(jnp.float32)
          + b_ref[...].astype(jnp.float32))
-    _pool_epilogue(y, out_step, nconv, w_out, cout, o_ref)
+    _pool_epilogue(y, out_step, rows, w_out, o_ref)
 
 
-def _popcount_kernel(*refs, rows: int, w_out: int, k9p: int, cout: int,
+def _popcount_kernel(*refs, rows: int, w_out: int, k9p: int,
                      out_step: float):
-    """Binary-domain fused conv+pool: the im2col codes stay uint32 bit
+    """Binary-domain fused conv+pool: the im2col codes stay 32-bit bit
     planes, contracted against the stored weight words with AND+popcount
     (the FPGA PE's XNOR tree); requant + 2×2 max fold into the same step.
     Uniform-Mul_prev contract: ops.py folds the scalar step into Div.
@@ -63,10 +73,10 @@ def _popcount_kernel(*refs, rows: int, w_out: int, k9p: int, cout: int,
     nconv = 2 * rows
     line_rows = [r[0, 0] for r in refs[:nconv + 2]]
     wp_ref, d_ref, b_ref, o_ref = refs[nconv + 2:]
-    cols = _im2col_rows(line_rows, nconv, w_out, k9p, jnp.uint32)
+    cols = _im2col_rows(line_rows, nconv, w_out, k9p)
     s = _xnor_accumulate(cols, wp_ref[...], k9p).astype(jnp.float32)
     y = s * d_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
-    _pool_epilogue(y, out_step, nconv, w_out, cout, o_ref)
+    _pool_epilogue(y, out_step, rows, w_out, o_ref)
 
 
 def w1a8_conv3x3_pool2(a_u8: jax.Array, w_packed: jax.Array,
@@ -74,7 +84,7 @@ def w1a8_conv3x3_pool2(a_u8: jax.Array, w_packed: jax.Array,
                        bias: jax.Array, *, cin: int, out_step: float,
                        accum: str = "dot", rows: int = 1,
                        compute_dtype=jnp.bfloat16,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool = False) -> jax.Array:
     """a_u8 (B,H,W,Cin) uint8 (H,W even) → (B,H/2,W/2,Cout) uint8 codes.
 
     ``rows`` pooled rows per grid step ((H/2) % rows == 0); bit-exact
@@ -111,7 +121,7 @@ def w1a8_conv3x3_pool2(a_u8: jax.Array, w_packed: jax.Array,
     bs = bias.astype(jnp.float32).reshape(1, cout)
     if accum == "popcount":
         kernel = functools.partial(_popcount_kernel, rows=rows, w_out=w,
-                                   k9p=k9p, cout=cout, out_step=out_step)
+                                   k9p=k9p, out_step=out_step)
         in_specs = row_specs + [wspec, cspec, cspec]
         operands = row_ops + (wp, dv, bs)
     else:

@@ -25,56 +25,63 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat  # noqa: F401  (pltpu.CompilerParams on older jax)
 from repro.core.packing import PACK
 from repro.core.quant import requant_epilogue
 from repro.kernels.w1a8_matmul.kernel import _unpack_tile, _xnor_accumulate
 
 
-def _im2col_rows(line_rows, nrows: int, w_out: int, k9p: int, dtype):
-    """Staged line buffers → (nrows·W, K9p) im2col block in (dy, dx, cin)
-    order — the "3x3 window former", one block row per output row."""
+def _im2col_rows(line_rows, nrows: int, w_out: int, k9p: int):
+    """Staged line buffers → (nrows·W, K9p) int32 im2col block in (dy, dx,
+    cin) order — the "3x3 window former", one block row per output row.
+    Each staged uint8 row is widened to int32 once, before any slicing:
+    the TPU casts uint8 only to 32-bit integers, and slices those freely."""
+    wide = [r.astype(jnp.int32) for r in line_rows]
     blocks = []
     for r in range(nrows):
         blocks.append(jnp.concatenate(
-            [line_rows[r + dy][dx:dx + w_out, :]
-             for dy in range(3) for dx in range(3)],
-            axis=-1).astype(dtype))                        # (W, 9Cin)
+            [wide[r + dy][dx:dx + w_out, :]
+             for dy in range(3) for dx in range(3)], axis=-1))  # (W, 9Cin)
     cols = blocks[0] if nrows == 1 else jnp.concatenate(blocks, axis=0)
     if cols.shape[1] < k9p:                                # K padding lanes
         cols = jnp.pad(cols, ((0, 0), (0, k9p - cols.shape[1])))
     return cols
 
 
+def _store_rows(o_ref, y, out_step: Optional[float], rows: int, w_out: int):
+    """Epilogue tail: (rows·W, Cout) f32 → ``rows`` output rows, requantized
+    to uint8 codes when ``out_step`` is given."""
+    if out_step is not None:
+        y = requant_epilogue(y, out_step, o_ref.dtype)
+    y = y.astype(o_ref.dtype)
+    for r in range(rows):
+        o_ref[0, r] = y[r * w_out:(r + 1) * w_out]
+
+
 def _conv_kernel(*refs, rows: int, w_out: int, k9p: int, cout: int,
                  out_step: Optional[float], compute_dtype):
     line_rows = [r[0, 0] for r in refs[:rows + 2]]        # each (Wp, Cin)
     wp_ref, m_ref, d_ref, b_ref, o_ref = refs[rows + 2:]
-    cols = _im2col_rows(line_rows, rows, w_out, k9p, jnp.float32)
+    cols = _im2col_rows(line_rows, rows, w_out, k9p).astype(jnp.float32)
     am = (cols * m_ref[...].astype(jnp.float32)).astype(compute_dtype)
     signs = _unpack_tile(wp_ref[...], k9p, cout, compute_dtype)
     y = jnp.dot(am, signs, preferred_element_type=jnp.float32)
     y = y * d_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
-    if out_step is not None:
-        y = requant_epilogue(y, out_step, o_ref.dtype)
-    o_ref[0] = y.astype(o_ref.dtype).reshape(rows, w_out, cout)
+    _store_rows(o_ref, y, out_step, rows, w_out)
 
 
-def _conv_popcount_kernel(*refs, rows: int, w_out: int, k9p: int, cout: int,
+def _conv_popcount_kernel(*refs, rows: int, w_out: int, k9p: int,
                           out_step: Optional[float]):
     """Binary-domain conv rows: the im2col codes never leave the 1-bit/8-bit
-    domain — bit-planes are packed to uint32 words and contracted against
+    domain — bit-planes are packed to 32-bit words and contracted against
     the stored weight words with AND+popcount (the FPGA PE's XNOR tree).
     Uniform-Mul_prev contract: ops.py folds the scalar step into Div.
     """
     line_rows = [r[0, 0] for r in refs[:rows + 2]]
     wp_ref, d_ref, b_ref, o_ref = refs[rows + 2:]
-    cols = _im2col_rows(line_rows, rows, w_out, k9p, jnp.uint32)
+    cols = _im2col_rows(line_rows, rows, w_out, k9p)
     s = _xnor_accumulate(cols, wp_ref[...], k9p).astype(jnp.float32)
     y = s * d_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
-    if out_step is not None:
-        y = requant_epilogue(y, out_step, o_ref.dtype)
-    o_ref[0] = y.astype(o_ref.dtype).reshape(rows, w_out, cout)
+    _store_rows(o_ref, y, out_step, rows, w_out)
 
 
 def w1a8_conv3x3_pallas(a_pad: jax.Array, w_packed: jax.Array,
@@ -111,8 +118,7 @@ def w1a8_conv3x3_pallas(a_pad: jax.Array, w_packed: jax.Array,
     cspec = pl.BlockSpec((1, cout), lambda bb, i: (0, 0))
     if accum == "popcount":
         kernel = functools.partial(_conv_popcount_kernel, rows=rows,
-                                   w_out=w_out, k9p=k9p, cout=cout,
-                                   out_step=out_step)
+                                   w_out=w_out, k9p=k9p, out_step=out_step)
         in_specs = row_specs + [wspec, cspec, cspec]
         operands = row_ops + (w_packed, div_post, bias)
     else:

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat  # noqa: F401  (pltpu.CompilerParams on older jax)
 from repro.core.packing import PACK
 from repro.core.quant import requant_epilogue
 
@@ -34,46 +33,72 @@ DEF_BM, DEF_BK, DEF_BN = 256, 512, 256
 
 
 def _unpack_tile(wp_tile: jax.Array, bk: int, bn: int, dtype) -> jax.Array:
-    """(bk/32, bn) uint32 → (bk, bn) ±1 in `dtype`, in VMEM."""
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (bk // PACK, PACK, bn), 1)
-    bits = (wp_tile[:, None, :] >> shifts) & jnp.uint32(1)
-    signs = bits.astype(jnp.int8) * jnp.int8(2) - jnp.int8(1)
+    """(bk/32, bn) uint32 → (bk, bn) ±1 in `dtype`, in VMEM.
+
+    The bits are tested as int32 and the ±1 values formed in f32: the TPU
+    vector unit multiplies no 8-bit integers and selects no 16-bit values.
+    """
+    w = jax.lax.bitcast_convert_type(wp_tile, jnp.int32)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (bk // PACK, PACK, bn), 1)
+    bits = (w[:, None, :] >> shifts) & 1
+    signs = jnp.where(bits != 0, 1.0, -1.0).astype(jnp.float32)
     return signs.reshape(bk, bn).astype(dtype)
 
 
-def _pack_act_bitplane(a_u32: jax.Array, bit: int, kp: int) -> jax.Array:
-    """Bit-plane ``bit`` of uint8 codes (M, Kp) → (M, Kp/32) uint32 words.
+def _word_packers(kp: int):
+    """(Kp, Kp/32) bf16 matrices that pack 0/1 lanes into 32-bit words.
 
-    Same LSB-first convention as ``core.packing.pack_signs`` so the words
-    AND directly against the stored weight sign words.
-    """
-    m = a_u32.shape[0]
-    bits = (a_u32 >> jnp.uint32(bit)) & jnp.uint32(1)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (m, kp // PACK, PACK), 2)
-    return jnp.sum(bits.reshape(m, kp // PACK, PACK) << shifts, axis=2,
-                   dtype=jnp.uint32)
+    ``bits @ lo`` gives the low 16 bits of each word, ``bits @ hi`` the
+    high 16: every entry is a power of two below 2^16 and every sum is
+    below 2^16, so both products are exact in bf16 × bf16 → f32. The MXU
+    does the packing because the vector unit cannot split a lane axis into
+    (words, 32) in place."""
+    k = jax.lax.broadcasted_iota(jnp.int32, (kp, kp // PACK), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (kp, kp // PACK), 1)
+    t = k & (PACK - 1)
+    own = (k >> 5) == j
+    half = PACK // 2
+    lo = jnp.where(own & (t < half), jnp.left_shift(1, t), 0)
+    hi = jnp.where(own & (t >= half), jnp.left_shift(1, t - half), 0)
+    return (lo.astype(jnp.float32).astype(jnp.bfloat16),
+            hi.astype(jnp.float32).astype(jnp.bfloat16))
 
 
-def _xnor_accumulate(a_u32: jax.Array, wp_tile: jax.Array,
+def _pack_act_bitplane(a_i32: jax.Array, bit: int, packers) -> jax.Array:
+    """Bit-plane ``bit`` of uint8 codes held as int32 (M, Kp) → (M, Kp/32)
+    int32 words, LSB first — the ``core.packing.pack_signs`` convention, so
+    the words AND directly against the stored weight sign words."""
+    lo_p, hi_p = packers
+    bits = ((a_i32 >> bit) & 1).astype(jnp.float32).astype(jnp.bfloat16)
+    lo = jnp.dot(bits, lo_p, preferred_element_type=jnp.float32)
+    hi = jnp.dot(bits, hi_p, preferred_element_type=jnp.float32)
+    return lo.astype(jnp.int32) | (hi.astype(jnp.int32) << (PACK // 2))
+
+
+def _xnor_accumulate(a_i32: jax.Array, wp_tile: jax.Array,
                      kp: int) -> jax.Array:
     """Σ_k sign_k·a_k via XNOR-popcount on packed words — exact int32.
 
-    a_u32: (M, Kp) uint8 codes held as uint32; wp_tile: (Kp/32, N) sign
-    words (bit=1 ⇔ +1). FracBNN-style bit decomposition: a = Σ_b 2^b·a_b
+    a_i32: (M, Kp) uint8 codes held as int32; wp_tile: (Kp/32, N) uint32
+    sign words (bit=1 ⇔ +1). FracBNN-style bit decomposition: a = Σ_b 2^b·a_b
     with a_b ∈ {0,1}, and for each binary plane
         Σ_k s_k·a_{b,k} = 2·popcount(w ∧ a_b) − popcount(a_b)
     so the whole inner product is bitwise AND + population_count — no
     unpack, no multiply. Zero codes contribute 0 to both terms, so K
-    padding lanes (zero activations, +1 weight pad bits) are free.
+    padding lanes (zero activations, +1 weight pad bits) are free. All
+    words are int32: the TPU vector unit reduces signed integers only.
     """
-    acc = jnp.zeros((a_u32.shape[0], wp_tile.shape[1]), jnp.int32)
+    w = jax.lax.bitcast_convert_type(wp_tile, jnp.int32)
+    packers = _word_packers(kp)
+    acc = jnp.zeros((a_i32.shape[0], w.shape[1]), jnp.int32)
     for bit in range(8):
-        words = _pack_act_bitplane(a_u32, bit, kp)          # (M, Kp/32)
-        pc = jnp.sum(jax.lax.population_count(
-            words[:, :, None] & wp_tile[None, :, :]).astype(jnp.int32),
-            axis=1)                                          # (M, N)
-        cnt = jnp.sum(jax.lax.population_count(words).astype(jnp.int32),
-                      axis=1, keepdims=True)                 # (M, 1)
+        words = _pack_act_bitplane(a_i32, bit, packers)     # (M, Kp/32)
+        pc = jnp.zeros_like(acc)
+        for j in range(kp // PACK):                         # (M,1) & (1,N)
+            pc = pc + jax.lax.population_count(
+                words[:, j:j + 1] & w[j:j + 1, :])
+        cnt = jnp.sum(jax.lax.population_count(words), axis=1,
+                      keepdims=True)                         # (M, 1)
         acc = acc + ((2 * pc - cnt) << bit)
     return acc
 
@@ -88,7 +113,7 @@ def _matmul_kernel(a_ref, wp_ref, m_ref, d_ref, b_ref, o_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # Prologue: per-input-channel Mul_prev fused before the contraction.
-    a = a_ref[...].astype(jnp.float32)            # (bm, bk) uint8 → f32
+    a = a_ref[...].astype(jnp.int32).astype(jnp.float32)   # uint8 → f32
     am = (a * m_ref[...].astype(jnp.float32)).astype(compute_dtype)
     signs = _unpack_tile(wp_ref[...], bk, bn, compute_dtype)
     acc_ref[...] += jnp.dot(am, signs, preferred_element_type=jnp.float32)
@@ -119,7 +144,7 @@ def _popcount_matmul_kernel(a_ref, wp_ref, d_ref, b_ref, o_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += _xnor_accumulate(a_ref[...].astype(jnp.uint32),
+    acc_ref[...] += _xnor_accumulate(a_ref[...].astype(jnp.int32),
                                      wp_ref[...], bk)
 
     @pl.when(kk == nk - 1)

@@ -41,7 +41,8 @@ from repro import configs                      # noqa: E402
 from repro.configs.shapes import SHAPES, skip_reason       # noqa: E402
 from repro.dist import sharding as shard_rules  # noqa: E402
 from repro.launch import dryrun as dr          # noqa: E402
-from repro.launch.mesh import HW, make_production_mesh     # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
+from repro.launch.peaks import V5E, chip_peaks  # noqa: E402
 from repro.models.layers import embed, norm, unembed       # noqa: E402
 from repro.models.transformer import (ShardCtx, _apply_slot,  # noqa: E402
                                       init_lm_params)
@@ -49,12 +50,13 @@ from repro.optim import adafactor, adamw       # noqa: E402
 from repro.serve import engine as serve_engine  # noqa: E402
 from repro.serve.packed import deploy_lm       # noqa: E402
 
+HW = chip_peaks(V5E)
+
 
 def _cost_of(jitted, *args):
-    from repro.compat import cost_analysis_dict
     lowered = jitted.lower(*args)
     compiled = lowered.compile()
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     coll = dr.parse_collectives(compiled.as_text())
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),

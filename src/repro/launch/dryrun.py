@@ -27,12 +27,15 @@ from repro.configs.shapes import SHAPES, skip_reason       # noqa: E402
 from repro.dist import sharding as shard_rules  # noqa: E402
 from repro.dist.pipeline import (bubble_fraction,           # noqa: E402
                                  bubble_fraction_1f1b)
-from repro.launch.mesh import HW, make_production_mesh     # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
+from repro.launch.peaks import V5E, chip_peaks  # noqa: E402
 from repro.models.transformer import ShardCtx, init_lm_params, lm_forward  # noqa: E402
 from repro.optim import adafactor, adamw       # noqa: E402
 from repro.serve import engine as serve_engine  # noqa: E402
 from repro.serve.packed import deploy_lm       # noqa: E402
 from repro.train.step import make_train_step   # noqa: E402
+
+HW = chip_peaks(V5E)
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                            "benchmarks", "results")
@@ -418,8 +421,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
             k: int(getattr(mem, k, 0) or 0)
             for k in ("argument_size_in_bytes", "output_size_in_bytes",
                       "temp_size_in_bytes", "generated_code_size_in_bytes")}
-        from repro.compat import cost_analysis_dict
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis() or {}
         flops = float(cost.get("flops", 0.0))
         bytes_acc = float(cost.get("bytes accessed", 0.0))
         rec["cost"] = {"flops": flops, "bytes_accessed": bytes_acc}

@@ -1,4 +1,4 @@
-"""Production mesh definitions (TPU v5e pods).
+"""Mesh construction for the training and dry-run launchers.
 
 Functions, not module constants — importing this module never touches jax
 device state (the dry-run sets XLA_FLAGS before first jax init).
@@ -6,6 +6,19 @@ device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis in ``Auto`` mode.
+
+    The sharding rules (`dist.sharding`) and the train steps place inputs
+    and leave propagation to the compiler; the ``Explicit`` axes that
+    ``jax.make_mesh`` defaults to would instead demand an output sharding
+    for every reshape of a sharded array (the microbatch split, for one).
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -13,7 +26,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     two pods = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2):
@@ -21,13 +34,4 @@ def make_test_mesh(data: int = 2, model: int = 2):
     n = len(jax.devices())
     if n < data * model:
         data, model = 1, min(n, model)
-    return jax.make_mesh((data, model), ("data", "model"))
-
-
-HW = {
-    "name": "TPU v5e",
-    "peak_flops_bf16": 197e12,      # per chip
-    "hbm_bw": 819e9,                # B/s per chip
-    "ici_bw": 50e9,                 # B/s per link (~per-direction)
-    "hbm_gib": 16,
-}
+    return make_mesh((data, model), ("data", "model"))

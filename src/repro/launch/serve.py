@@ -526,6 +526,8 @@ def main():
                          "img_per_s at the chosen K drops >5%% below it "
                          "(detect/multires)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if not args.buckets:
         args.buckets = "256,320" if args.workload == "multires" else "320"
 

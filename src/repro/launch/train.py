@@ -60,14 +60,17 @@ def main():
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro import configs
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.data import pipeline as data
     from repro.dist import sharding as shard_rules
+    from repro.launch.mesh import make_mesh
     from repro.models.transformer import ShardCtx, init_lm_params
     from repro.optim import adafactor, adamw, sgdm
     from repro.optim.schedules import cosine_schedule
     from repro.train.loop import resume_or_init, run_train
     from repro.train.step import make_pipeline_train_step, make_train_step
 
+    enable_compile_cache()
     cfg = (configs.get_reduced(args.arch) if args.reduced
            else configs.get_config(args.arch))
     sched = cosine_schedule(args.lr, max(args.steps // 20, 1), args.steps)
@@ -91,7 +94,7 @@ def main():
         if n_dev % n_st:
             raise SystemExit(f"{n_dev} devices do not split into "
                              f"{n_st} pipeline stages")
-        mesh = jax.make_mesh((n_dev // n_st, n_st), ("data", "stage"))
+        mesh = make_mesh((n_dev // n_st, n_st), ("data", "stage"))
         num_micro = max(args.microbatches, 1)
         raw_step = make_pipeline_train_step(
             cfg, opt, mesh=mesh, num_micro=num_micro, mode=args.mode,

@@ -25,7 +25,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat  # noqa: F401  (jax.lax.axis_size shim on older jax)
 from repro.core.quant import binarize_ste, lsq_fake_quant, lsq_grad_scale
 from repro.models.layers import ModelConfig, _act
 

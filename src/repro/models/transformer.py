@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat  # noqa: F401  (jax.shard_map shim on older jax)
 from repro.models import mamba as mb
 from repro.models import moe as moe_mod
 from repro.models.layers import (ModelConfig, attention, embed,

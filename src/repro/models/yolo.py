@@ -135,9 +135,12 @@ def count_gflops() -> dict:
 # ---------------------------------------------------------------------------
 
 def _conv2d(x: jax.Array, w: jax.Array) -> jax.Array:
+    # HIGHEST: the TPU's default precision rounds f32 operands to bf16,
+    # which would drop bits of the Q5.11 conv1 and Q1.15 head weights.
     pad = "SAME" if w.shape[0] == 3 else "VALID"
     return jax.lax.conv_general_dilated(
-        x, w, (1, 1), pad, dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        x, w, (1, 1), pad, dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        precision=jax.lax.Precision.HIGHEST)
 
 
 def _maxpool2(x: jax.Array) -> jax.Array:
@@ -502,6 +505,25 @@ def _layer_config(spec: ConvSpec, h: int, batch: int, *, profile: str,
     return cfg.replace(out_step=1.0)
 
 
+def layer_configs(art: dict, size: int, batch: int, *,
+                  profile: str = None, accum: str = None,
+                  fuse_pool: bool = None, interpret: bool = None) -> list:
+    """[(layer name, KernelConfig)] for every W1A8 layer of ``art`` at one
+    (image side, batch) — exactly what `yolo_forward_kernel` launches, so
+    callers can report the configs a served bundle runs with."""
+    if profile is None:
+        profile = "default"
+    if profile not in PROFILES:
+        raise ValueError(f"profile must be one of {PROFILES}, got {profile!r}")
+    table = _cfg.load_table() if profile == "tuned" else None
+    sizes = spatial_sizes(size)
+    return [(e["spec"].name,
+             _layer_config(e["spec"], sizes[e["spec"].name], batch,
+                           profile=profile, accum=accum, fuse_pool=fuse_pool,
+                           interpret=interpret, table=table))
+            for e in art["layers"][1:-1]]
+
+
 def yolo_forward_kernel(art: dict, images: jax.Array, *,
                         profile: str = None,
                         interpret: bool = None,
@@ -517,12 +539,12 @@ def yolo_forward_kernel(art: dict, images: jax.Array, *,
     streaming analogue; the codes+step pair crosses every layer boundary
     as one object.
 
-    Per-layer launch configuration comes from ``profile``:
+    Per-layer launch configuration comes from ``profile``
+    (`layer_configs`):
 
-    * ``"interpret"`` (default) — heuristic tiles, interpret-mode Pallas;
-      today's behavior everywhere.
-    * ``"default"`` — heuristic tiles, interpret auto-resolved from the
-      backend (compiled on real TPUs).
+    * ``"default"`` (default) — heuristic tiles; Pallas compiled on a TPU
+      and interpreted on any other backend.
+    * ``"interpret"`` — heuristic tiles, interpret-mode Pallas everywhere.
     * ``"tuned"`` — per-layer winners from the committed autotune table
       (`kernels/config.resolve`, exact → nearest-shape → heuristic),
       including fastest-accum selection and the fused-vs-unfused pool
@@ -540,19 +562,11 @@ def yolo_forward_kernel(art: dict, images: jax.Array, *,
     accumulation already sit on a per-tensor grid (DESIGN.md §16). All
     three kwargs override the profile.
     """
-    if profile is None:
-        profile = "interpret"
-    if profile not in PROFILES:
-        raise ValueError(f"profile must be one of {PROFILES}, got {profile!r}")
     layers = art["layers"]
-    table = _cfg.load_table() if profile == "tuned" else None
-    sizes = spatial_sizes(images.shape[1])          # static under jit
-    batch = images.shape[0]
     w1a8 = layers[1:-1]
-    cfgs = [_layer_config(e["spec"], sizes[e["spec"].name], batch,
-                          profile=profile, accum=accum, fuse_pool=fuse_pool,
-                          interpret=interpret, table=table)
-            for e in w1a8]
+    cfgs = [cfg for _, cfg in layer_configs(
+        art, images.shape[1], images.shape[0], profile=profile, accum=accum,
+        fuse_pool=fuse_pool, interpret=interpret)]
 
     def boundary_step(step_out, i):
         # the step the producer's epilogue quantizes ONTO; popcount

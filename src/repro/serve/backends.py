@@ -328,8 +328,8 @@ class DetectionBackend:
     table); ``"interpret"`` reproduces the historical heuristic/interpret
     behavior; ``"default"`` is heuristics with backend-resolved compile
     mode. The old raw kernel kwargs (``interpret=``, ``fuse_pool=``)
-    survive one release behind a DeprecationWarning and force the
-    equivalent profile override.
+    survive one release behind a DeprecationWarning: they select
+    ``"default"`` and override its matching field.
 
     ``device_nms=True`` changes the emission wire, not the math: the NMS
     always runs inside the one executable, but the default wire still ships
@@ -360,7 +360,7 @@ class DetectionBackend:
                 raise TypeError("pass either profile= or the legacy "
                                 "interpret=/fuse_pool= kwargs, not both")
             _warn_detect_kwargs_once()
-            profile = "interpret"            # the historical default regime
+            profile = "default"      # heuristics; interpret only if asked
             if interpret is not _UNSET:
                 overrides["interpret"] = interpret
             if fuse_pool is not _UNSET:
@@ -466,6 +466,14 @@ class DetectionBackend:
                 f"request {req.rid}: image size {size} matches no "
                 f"configured bucket {self.buckets}")
         return size
+
+    def lower(self, bucket: int, *, sharding=None):
+        """AOT-lower one bucket's fixed-width bundle (``.compile()`` it to
+        inspect the executable a serving tick runs). ``sharding`` places
+        the image batch, e.g. on a described device with no chip attached.
+        """
+        return self._fwd.lower(jax.ShapeDtypeStruct(
+            (self.width, bucket, bucket, 3), jnp.float32, sharding=sharding))
 
     def warmup(self) -> None:
         """Compile + run every bucket's fixed-width bundle once so serving

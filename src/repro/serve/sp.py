@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat  # noqa: F401  (jax.shard_map shim on older jax)
-
 
 def sp_attention_local(q, k_local, v_local, pos_local, cur_pos):
     """Partial attention of one shard. q (B,H,hd); k/v (B,T_l,KV,hd);

@@ -28,6 +28,7 @@ from repro.dist.collectives import tree_quantized_allreduce  # noqa: E402
 from repro.dist.pipeline import (gpipe, pipeline_train_reference,  # noqa: E402
                                  pipeline_train_step)
 from repro.dist import sharding as shard_rules  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import moe as moe_mod  # noqa: E402
 from repro.models.layers import ModelConfig  # noqa: E402
 from repro.models.transformer import ShardCtx, init_lm_params  # noqa: E402
@@ -45,7 +46,7 @@ def check_moe_ep():
     x = jax.random.normal(jax.random.PRNGKey(1), (32, 32))
     for mode in ("float", "w1a8_train"):
         y_ref = moe_mod.moe_ffn(p, cfg, x, mode=mode, ep_axis=None)
-        mesh = jax.make_mesh((4, 4), ("data", "model"))
+        mesh = make_mesh((4, 4), ("data", "model"))
 
         def inner(pl, xl):
             return moe_mod.moe_ffn(pl, cfg, xl, mode=mode, ep_axis="data",
@@ -63,7 +64,7 @@ def check_moe_ep():
 
 
 def check_gpipe():
-    mesh = jax.make_mesh((4, 4), ("pod", "model"))
+    mesh = make_mesh((4, 4), ("pod", "model"))
     n_stages, num_micro, mb, d = 4, 8, 2, 16
     ws = jax.random.normal(jax.random.PRNGKey(2), (n_stages, d, d)) * 0.3
 
@@ -83,7 +84,7 @@ def check_gpipe():
 
 
 def check_quantized_allreduce():
-    mesh = jax.make_mesh((16,), ("data",))
+    mesh = make_mesh((16,), ("data",))
     g = jax.random.normal(jax.random.PRNGKey(4), (16, 64, 64))
 
     def inner(gl):
@@ -112,7 +113,7 @@ def check_sharded_train_step():
     s_ref = make_train_step(cfg, opt, remat=False)
     _, _, m_ref = s_ref(params, opt[0](params), batch)
 
-    mesh = jax.make_mesh((4, 4), ("data", "model"))
+    mesh = make_mesh((4, 4), ("data", "model"))
     ctx = ShardCtx(mesh=mesh, dp_axes=("data",), tp_axis="model",
                    ep_axis="data")
     p_sh = shard_rules.tree_shardings(params, cfg, mesh)
@@ -142,7 +143,7 @@ def _tree_rel_err(got, want) -> float:
 
 
 def check_pipeline_train():
-    mesh = jax.make_mesh((4, 4), ("stage", "data"))
+    mesh = make_mesh((4, 4), ("stage", "data"))
     n, num_micro, mb, d = 4, 8, 2, 16
     key = jax.random.PRNGKey(8)
     ws = {"w": jax.random.normal(key, (n, d, d)) * 0.3,
@@ -213,7 +214,7 @@ def check_pipeline_lm_train_step():
     s_ref = make_train_step(cfg, opt, remat=False)
     _, _, m_ref = s_ref(params, opt[0](params), batch)
 
-    mesh = jax.make_mesh((8, 2), ("data", "stage"))
+    mesh = make_mesh((8, 2), ("data", "stage"))
     p_sh = shard_rules.pipeline_tree_shardings(params, mesh,
                                                cfg.num_layers)
     s_pipe = jax.jit(make_pipeline_train_step(cfg, opt, mesh=mesh,
@@ -233,7 +234,7 @@ def check_pipeline_lm_train_step():
 
 def check_sp_attention():
     from repro.serve.sp import sp_decode_attention
-    mesh = jax.make_mesh((16,), ("data",))
+    mesh = make_mesh((16,), ("data",))
     b, h, kv, hd, t = 2, 8, 4, 16, 64
     key = jax.random.PRNGKey(7)
     q = jax.random.normal(key, (b, h, hd))

@@ -6,6 +6,8 @@ import sys
 
 import jax
 
+from repro.launch.mesh import make_mesh
+
 
 def test_multi_device_suite():
     """EP MoE, TP-in-expert, GPipe, int8 all-reduce, sharded train, SP attn,
@@ -28,7 +30,7 @@ def test_sharding_rules_cover_all_archs():
     from repro.dist import sharding as shard_rules
     from repro.models.transformer import init_lm_params
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for name in configs.ARCH_NAMES:
         cfg = configs.get_config(name)
         sds = jax.eval_shape(

@@ -300,7 +300,7 @@ def test_detection_backend_legacy_kwargs_warn(tiny_detector):
     backends._detect_kwargs_warned = False
     with pytest.warns(DeprecationWarning, match="profile"):
         be = backends.DetectionBackend(art, slots=1, fuse_pool=False)
-    assert be.profile == "interpret"
+    assert be.profile == "default"
     with pytest.raises(TypeError, match="not both"):
         backends.DetectionBackend(art, slots=1, profile="tuned",
                                   interpret=True)

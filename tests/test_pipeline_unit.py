@@ -20,6 +20,7 @@ from repro.dist.pipeline import (
     pipeline_train_reference,
     pipeline_train_step,
 )
+from repro.launch.mesh import make_mesh
 
 needs_devices = pytest.mark.skipif(
     len(jax.devices()) < 16,
@@ -144,7 +145,7 @@ def test_pipeline_train_matches_oracle(schedule):
     loss_ref, gws_ref, gtop_ref, dx_ref = pipeline_train_reference(
         _stage_fn, _loss_fn, ws, x, aux=aux, top=top
     )
-    mesh = jax.make_mesh((n,), ("stage",))
+    mesh = make_mesh((n,), ("stage",))
     step = pipeline_train_step(
         _stage_fn,
         _loss_fn,
@@ -174,7 +175,7 @@ def test_act_wire_int8_envelope(schedule):
     loss_ref, gws_ref, gtop_ref, dx_ref = pipeline_train_reference(
         _stage_fn, _loss_fn, ws, x, aux=aux, top=top
     )
-    mesh = jax.make_mesh((n,), ("stage",))
+    mesh = make_mesh((n,), ("stage",))
     step = pipeline_train_step(
         _stage_fn,
         _loss_fn,
@@ -213,7 +214,7 @@ def test_act_wire_b1_envelope(schedule):
     loss_ref, gws_ref, gtop_ref, dx_ref = _b1_wire_reference(
         _stage_fn, _loss_fn, ws, x, aux, top
     )
-    mesh = jax.make_mesh((n,), ("stage",))
+    mesh = make_mesh((n,), ("stage",))
     step = pipeline_train_step(
         _stage_fn,
         _loss_fn,
@@ -239,7 +240,7 @@ def test_act_wire_b1_envelope(schedule):
 def test_act_wire_validated():
     with pytest.raises(ValueError, match="act_wire"):
         pipeline_train_step(_stage_fn, _loss_fn,
-                            mesh=jax.make_mesh((2,), ("stage",)),
+                            mesh=make_mesh((2,), ("stage",)),
                             axis="stage", num_micro=2, act_wire="fp16")
 
 
@@ -249,7 +250,7 @@ def test_dp_grad_wire_envelope(wire, tol):
     n, num_micro = 2, 4
     ws, top, x, aux = _toy(n, num_micro, mb=8)
     ref = pipeline_train_reference(_stage_fn, _loss_fn, ws, x, aux=aux, top=top)
-    mesh = jax.make_mesh((n, 8), ("stage", "data"))
+    mesh = make_mesh((n, 8), ("stage", "data"))
     step = pipeline_train_step(
         _stage_fn,
         _loss_fn,

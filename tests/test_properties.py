@@ -16,6 +16,7 @@ from repro.core import fixedpoint as fxp
 from repro.core import packing
 from repro.core.quant import (ACT_QMAX, binarize_weight, quantize_act,
                               round_half_away, sign_accumulate_fused)
+from repro.launch.mesh import make_mesh
 
 SET = dict(deadline=None, max_examples=25)
 
@@ -229,7 +230,7 @@ def test_int8_wire_permute_roundtrip_within_envelope(x, mag, flip):
     if len(jax.devices()) < 4:
         pytest.skip("needs >= 4 host devices (see conftest.py)")
     x = x * np.float32(mag) * (np.float32(-1.0) if flip else np.float32(1.0))
-    mesh = jax.make_mesh((4,), ("d",))
+    mesh = make_mesh((4,), ("d",))
     spec = jax.sharding.PartitionSpec("d")
     shift = [(i, i + 1) for i in range(3)]        # ring edge stays dark
     fn = jax.jit(jax.shard_map(lambda s: permute_quantized(s, "d", shift),

@@ -75,3 +75,12 @@ def test_skip_reasons_match_design():
     assert not skip_reason("mamba2-1.3b", "long_500k")
     assert not skip_reason("mixtral-8x7b", "long_500k")
     assert not skip_reason("gemma2-27b", "train_4k")
+
+
+def test_peaks_keyed_by_device_kind_unknown_kind_raises():
+    import pytest
+    from repro.launch.peaks import V5E, chip_peaks
+    assert chip_peaks(V5E)["peak_flops_bf16"] == 197e12
+    assert chip_peaks(V5E)["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        chip_peaks("cpu")

@@ -16,6 +16,7 @@ from conftest import FakeProdMesh
 from repro import configs
 from repro.dist import sharding as shard_rules
 from repro.dist.sharding import dp_axes, param_spec
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import init_lm_params
 
 
@@ -44,7 +45,7 @@ def _assert_legal(path, shape, spec, mesh):
 @pytest.mark.parametrize("name", configs.ARCH_NAMES)
 def test_every_param_leaf_gets_a_sharding(name):
     cfg, sds = _params_sds(name)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = shard_rules.tree_shardings(sds, cfg, mesh)
     n_params = len(jax.tree_util.tree_leaves(sds))
     shardings = jax.tree_util.tree_leaves(sh)
@@ -105,7 +106,7 @@ def test_optimizer_and_packed_trees_inherit_legal_specs():
 
 
 def test_dp_axes():
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+    mesh1 = make_mesh((1, 1), ("data", "model"))
     assert dp_axes(mesh1) == ("data",)
 
     class Pod:

@@ -10,6 +10,7 @@ import pytest
 from repro import ckpt as ckpt_lib
 from repro import configs
 from repro.data import pipeline as data
+from repro.launch.mesh import make_mesh
 from repro.models import yolo
 from repro.models.transformer import init_lm_params
 from repro.optim import adafactor, adamw, apply_updates, sgdm
@@ -149,7 +150,7 @@ def test_elastic_restore_new_sharding(tmp_path):
         return
     # largest power-of-two mesh that still divides the (8, 8) leaf
     n = next(d for d in (8, 4, 2) if len(devs) >= d)
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = make_mesh((n,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = {"w": NamedSharding(mesh, P("data", None))}
     restored, _ = ckpt_lib.restore_checkpoint(d, 1, tree, shardings=sh)
