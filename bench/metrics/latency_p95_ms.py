@@ -1,0 +1,11 @@
+"""95th percentile of (answer time - due time) over every frame due in
+the window (not a median of chunks); see latency_p50_ms."""
+import numpy as np
+
+
+def read(run):
+    due = run.in_window()
+    lat = np.where(run.ok, run.done - run.due, np.inf)[due] * 1e3
+    if not len(lat) or not np.isfinite(lat).all():
+        return None
+    return float(np.percentile(lat, 95))
