@@ -17,6 +17,7 @@ one dataflow, which is the paper's whole §5.2 stage chain in one kernel.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -84,7 +85,8 @@ def w1a8_conv3x3_pool2(a_u8: jax.Array, w_packed: jax.Array,
                        bias: jax.Array, *, cin: int, out_step: float,
                        accum: str = "dot", rows: int = 1,
                        compute_dtype=jnp.bfloat16,
-                       interpret: bool = False) -> jax.Array:
+                       interpret: bool = False,
+                       name: Optional[str] = None) -> jax.Array:
     """a_u8 (B,H,W,Cin) uint8 (H,W even) → (B,H/2,W/2,Cout) uint8 codes.
 
     ``rows`` pooled rows per grid step ((H/2) % rows == 0); bit-exact
@@ -142,4 +144,5 @@ def w1a8_conv3x3_pool2(a_u8: jax.Array, w_packed: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(*operands)

@@ -89,7 +89,8 @@ def w1a8_conv3x3_pallas(a_pad: jax.Array, w_packed: jax.Array,
                         bias: jax.Array, *, out_step: Optional[float] = None,
                         accum: str = "dot", rows: int = 1,
                         compute_dtype=jnp.bfloat16,
-                        interpret: bool = False) -> jax.Array:
+                        interpret: bool = False,
+                        name: Optional[str] = None) -> jax.Array:
     """a_pad: (B, H+2, W+2, Cin) uint8 (SAME-padded, K-padding included in
     w/mul layout); w_packed: (K9p/32, Cout); mul9: (1, K9p) with zeros in
     padded lanes; div_post/bias: (1, Cout). Returns (B, H, W, Cout).
@@ -140,4 +141,5 @@ def w1a8_conv3x3_pallas(a_pad: jax.Array, w_packed: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(*operands)

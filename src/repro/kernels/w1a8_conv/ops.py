@@ -46,7 +46,7 @@ def w1a8_conv3x3(a_u8: jax.Array, w_packed: jax.Array, mul_prev: jax.Array,
                  div_post: jax.Array, bias: jax.Array, *, cin: int,
                  config: Optional[KernelConfig] = None,
                  out_step=_UNSET, accum=_UNSET, interpret=_UNSET,
-                 use_kernel=_UNSET) -> jax.Array:
+                 use_kernel=_UNSET, name: Optional[str] = None) -> jax.Array:
     """Streaming 3×3 SAME conv on uint8 codes.
 
     a_u8 (B,H,W,Cin); w_packed (ceil(9Cin/32),Cout); mul_prev (Cin,);
@@ -64,17 +64,20 @@ def w1a8_conv3x3(a_u8: jax.Array, w_packed: jax.Array, mul_prev: jax.Array,
     popcount-vs-dot bit-exactness contract holds; under per-channel steps
     it is an ≤½-LSB-per-channel approximation (the producer-side fold in
     ``models/yolo.py`` avoids even that by emitting uniform-step codes).
+
+    ``name`` names the Pallas call (its custom call in a compiled program).
     """
     cfg = _cfg.normalize("conv3x3", config, out_step=out_step, accum=accum,
                          interpret=interpret, use_kernel=use_kernel)
     cfg = cfg.replace(interpret=cfg.resolved_interpret())
     return _w1a8_conv3x3(a_u8, w_packed, mul_prev, div_post, bias,
-                         cin=cin, config=cfg)
+                         cin=cin, config=cfg, name=name)
 
 
-@functools.partial(jax.jit, static_argnames=("cin", "config"))
+@functools.partial(jax.jit, static_argnames=("cin", "config", "name"))
 def _w1a8_conv3x3(a_u8, w_packed, mul_prev, div_post, bias, *, cin: int,
-                  config: KernelConfig) -> jax.Array:
+                  config: KernelConfig, name: Optional[str] = None
+                  ) -> jax.Array:
     out_step = config.out_step
     if not config.use_kernel:
         return _ref.w1a8_conv3x3_ref(
@@ -96,7 +99,7 @@ def _w1a8_conv3x3(a_u8, w_packed, mul_prev, div_post, bias, *, cin: int,
         bias.astype(jnp.float32).reshape(1, cout),
         out_step=out_step, accum=config.accum,
         rows=config.conv_rows(a_u8.shape[1]),
-        interpret=config.interpret)
+        interpret=config.interpret, name=name)
 
 
 def w1a8_conv3x3_pool(a_u8: jax.Array, w_packed: jax.Array,
@@ -104,7 +107,8 @@ def w1a8_conv3x3_pool(a_u8: jax.Array, w_packed: jax.Array,
                       bias: jax.Array, *, cin: int,
                       config: Optional[KernelConfig] = None,
                       out_step=_UNSET, interpret=_UNSET,
-                      use_kernel=_UNSET) -> jax.Array:
+                      use_kernel=_UNSET,
+                      name: Optional[str] = None) -> jax.Array:
     """Streaming 3×3 SAME conv + requant + 2×2 MaxPool.
 
     Same contract as `w1a8_conv3x3` with a quantizing epilogue, but H and W
@@ -121,12 +125,13 @@ def w1a8_conv3x3_pool(a_u8: jax.Array, w_packed: jax.Array,
     if cfg.out_step is None:
         cfg = cfg.replace(out_step=1.0)
     return _w1a8_conv3x3_pool(a_u8, w_packed, mul_prev, div_post, bias,
-                              cin=cin, config=cfg)
+                              cin=cin, config=cfg, name=name)
 
 
-@functools.partial(jax.jit, static_argnames=("cin", "config"))
+@functools.partial(jax.jit, static_argnames=("cin", "config", "name"))
 def _w1a8_conv3x3_pool(a_u8, w_packed, mul_prev, div_post, bias, *,
-                       cin: int, config: KernelConfig) -> jax.Array:
+                       cin: int, config: KernelConfig,
+                       name: Optional[str] = None) -> jax.Array:
     out_step = config.out_step
     if not config.use_kernel:
         out = _ref.w1a8_conv3x3_ref(a_u8, w_packed, cin, mul_prev, div_post,
@@ -135,7 +140,8 @@ def _w1a8_conv3x3_pool(a_u8, w_packed, mul_prev, div_post, bias, *,
                                      (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
     if not config.fused:
         out = _w1a8_conv3x3(a_u8, w_packed, mul_prev, div_post, bias,
-                            cin=cin, config=config.replace(op="conv3x3"))
+                            cin=cin, config=config.replace(op="conv3x3"),
+                            name=name)
         return jax.lax.reduce_window(out, jnp.uint8(0), jax.lax.max,
                                      (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
     from repro.kernels.w1a8_conv.fused_pool import w1a8_conv3x3_pool2
@@ -146,4 +152,4 @@ def _w1a8_conv3x3_pool(a_u8, w_packed, mul_prev, div_post, bias, *,
     return w1a8_conv3x3_pool2(a_u8, w_packed, mul_prev, dv, bias,
                               cin=cin, out_step=out_step, accum=config.accum,
                               rows=config.conv_rows(a_u8.shape[1] // 2),
-                              interpret=config.interpret)
+                              interpret=config.interpret, name=name)

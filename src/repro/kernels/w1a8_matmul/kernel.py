@@ -162,7 +162,8 @@ def w1a8_matmul_popcount_pallas(a_u8: jax.Array, w_packed: jax.Array,
                                 out_step: Optional[float] = None,
                                 bm: int = DEF_BM, bk: int = DEF_BK,
                                 bn: int = DEF_BN,
-                                interpret: bool = False) -> jax.Array:
+                                interpret: bool = False,
+                                name: Optional[str] = None) -> jax.Array:
     """Binary-domain matmul: same shapes/epilogue as ``w1a8_matmul_pallas``
     minus the Mul_prev operand (already folded into ``div_post``)."""
     m, k = a_u8.shape
@@ -187,6 +188,7 @@ def w1a8_matmul_popcount_pallas(a_u8: jax.Array, w_packed: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(a_u8, w_packed, div_post, bias)
 
 
@@ -196,7 +198,8 @@ def w1a8_matmul_pallas(a_u8: jax.Array, w_packed: jax.Array,
                        out_step: Optional[float] = None,
                        bm: int = DEF_BM, bk: int = DEF_BK, bn: int = DEF_BN,
                        compute_dtype=jnp.bfloat16,
-                       interpret: bool = False) -> jax.Array:
+                       interpret: bool = False,
+                       name: Optional[str] = None) -> jax.Array:
     """Shapes (pre-padded to tile multiples by ops.py):
     a_u8 (M, K) uint8 · w_packed (K/32, N) uint32 · mul_prev (1, K) f32 ·
     div_post/bias (1, N) f32 → (M, N) f32, or uint8 codes when out_step given.
@@ -225,6 +228,7 @@ def w1a8_matmul_pallas(a_u8: jax.Array, w_packed: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name=name,
     )(a_u8, w_packed, mul_prev, div_post, bias)
 
 

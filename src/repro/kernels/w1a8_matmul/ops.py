@@ -25,7 +25,7 @@ def w1a8_matmul(a_u8: jax.Array, w_packed: jax.Array, mul_prev: jax.Array,
                 div_post: jax.Array, bias: jax.Array, *, k: int,
                 config: Optional[KernelConfig] = None,
                 out_step=_UNSET, accum=_UNSET, interpret=_UNSET,
-                use_kernel=_UNSET) -> jax.Array:
+                use_kernel=_UNSET, name: Optional[str] = None) -> jax.Array:
     """y = ((a ⊙ mul_prev) @ unpack(w_packed)) ⊙ div_post + bias  [+ requant].
 
     a_u8: (..., K) uint8 codes; w_packed: (ceil(K/32), N) uint32;
@@ -38,18 +38,19 @@ def w1a8_matmul(a_u8: jax.Array, w_packed: jax.Array, mul_prev: jax.Array,
     max step m̄ (`core.quant.fold_codes_to_uniform_step`), which then folds
     into div_post; under a uniform mul_prev the fold is a bit-exact
     identity, so the epilogue — and the rounding — matches the dot path
-    bit for bit.
+    bit for bit. ``name`` names the Pallas call.
     """
     cfg = _cfg.normalize("matmul", config, out_step=out_step, accum=accum,
                          interpret=interpret, use_kernel=use_kernel)
     cfg = cfg.replace(interpret=cfg.resolved_interpret())
     return _w1a8_matmul(a_u8, w_packed, mul_prev, div_post, bias,
-                        k=k, config=cfg)
+                        k=k, config=cfg, name=name)
 
 
-@functools.partial(jax.jit, static_argnames=("k", "config"))
+@functools.partial(jax.jit, static_argnames=("k", "config", "name"))
 def _w1a8_matmul(a_u8, w_packed, mul_prev, div_post, bias, *, k: int,
-                 config: KernelConfig) -> jax.Array:
+                 config: KernelConfig, name: Optional[str] = None
+                 ) -> jax.Array:
     out_step = config.out_step
     if not config.use_kernel:
         y = _ref.w1a8_matmul_ref(a_u8, w_packed, k, mul_prev, div_post, bias,
@@ -82,11 +83,12 @@ def _w1a8_matmul(a_u8, w_packed, mul_prev, div_post, bias, *, k: int,
         dv = dv * mbar
         y = _k.w1a8_matmul_popcount_pallas(a2, wp, dv, bs, out_step=out_step,
                                            bm=bm, bk=bk, bn=bn,
-                                           interpret=config.interpret)
+                                           interpret=config.interpret,
+                                           name=name)
     else:
         y = _k.w1a8_matmul_pallas(a2, wp, mul, dv, bs, out_step=out_step,
                                   bm=bm, bk=bk, bn=bn,
-                                  interpret=config.interpret)
+                                  interpret=config.interpret, name=name)
     return y[:m, :n].reshape(lead + (n,))
 
 

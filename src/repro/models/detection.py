@@ -89,12 +89,15 @@ import functools
                    static_argnames=("iou_thresh", "score_thresh", "max_out"))
 def postprocess(raw: jax.Array, *, iou_thresh: float = 0.45,
                 score_thresh: float = 0.25, max_out: int = 50):
-    """Full post-processing for a batch of raw heads."""
-    dec = decode_head(raw)
-    return jax.vmap(lambda b, s: nms(b, s, iou_thresh=iou_thresh,
-                                     score_thresh=score_thresh,
-                                     max_out=max_out))(dec["boxes"],
-                                                       dec["scores"])
+    """Full post-processing for a batch of raw heads (named scopes
+    ``decode`` and ``nms``)."""
+    with jax.named_scope("decode"):
+        dec = decode_head(raw)
+    with jax.named_scope("nms"):
+        return jax.vmap(lambda b, s: nms(b, s, iou_thresh=iou_thresh,
+                                         score_thresh=score_thresh,
+                                         max_out=max_out))(dec["boxes"],
+                                                           dec["scores"])
 
 
 def compact_detections(boxes: jax.Array, scores: jax.Array,

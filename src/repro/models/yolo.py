@@ -576,45 +576,56 @@ def yolo_forward_kernel(art: dict, images: jax.Array, *,
         return step_out
 
     # conv1 (std, fixed-point-rounded weights) in f32, then quantize to codes.
-    w1 = fxp.CONV1_W.roundtrip(layers[0]["w"])
-    b1 = fxp.CONV1_B.roundtrip(layers[0]["b"])
-    x = jax.nn.relu(_conv2d(images, w1) + b1)
-    x = _maxpool2(x)
-    qx = QTensor.quantize_u8(x, boundary_step(layers[0]["step_out"], 0),
-                             axis=-1)
+    # Every layer runs under a named scope of its name, so that a trace's
+    # ops map to layers through their metadata.
+    with jax.named_scope(layers[0]["spec"].name):
+        w1 = fxp.CONV1_W.roundtrip(layers[0]["w"])
+        b1 = fxp.CONV1_B.roundtrip(layers[0]["b"])
+        x = jax.nn.relu(_conv2d(images, w1) + b1)
+        x = _maxpool2(x)
+        qx = QTensor.quantize_u8(x, boundary_step(layers[0]["step_out"], 0),
+                                 axis=-1)
 
     for i, entry in enumerate(w1a8):
         spec: ConvSpec = entry["spec"]
-        cfg = cfgs[i]
-        # Mul_prev = this layer's input steps (= qx.scale: the QTensor
-        # carries exactly the dequant context the next kernel fuses);
-        # per-channel requant is folded into the epilogue:
-        # q = round(acc·(α/s_next) + b/s_next), out_step=1.
-        mul_prev = qx.scale
-        s_next = boundary_step(entry["step_out"], i + 1)   # (cout,) vector
-        div_eff = entry["alpha"] / s_next
-        b_eff = entry["b"] / s_next
-        if spec.ksize == 3 and spec.pool:
-            codes = conv_ops.w1a8_conv3x3_pool(
-                qx.data, entry["w_packed"], mul_prev, div_eff, b_eff,
-                cin=spec.cin, config=cfg)
-            qx = QTensor.from_codes(codes, s_next, axis=-1)
-            continue
-        if spec.ksize == 3:
-            out = conv_ops.w1a8_conv3x3(
-                qx.data, entry["w_packed"], mul_prev, div_eff, b_eff,
-                cin=spec.cin, config=cfg)
-        else:
-            b, h, w, _ = qx.data.shape
-            out = mm_ops.w1a8_matmul(
-                qx.data.reshape(b * h * w, spec.cin), entry["w_packed"],
-                mul_prev, div_eff, b_eff, k=spec.cin, config=cfg)
-            out = out.reshape(b, h, w, spec.cout)
-        qx = QTensor.from_codes(out, s_next, axis=-1)
+        with jax.named_scope(spec.name):
+            qx = _w1a8_layer(spec, entry, qx, cfgs[i],
+                             boundary_step(entry["step_out"], i + 1))
 
     # conv11 detection head (std 1×1, fixed-point weights) on dequant codes.
     last = layers[-1]
-    xq = qx.dequantize()
-    w11 = fxp.CONV11_W.roundtrip(last["w"])
-    b11 = fxp.CONV11_B.roundtrip(last["b"])
-    return _conv2d(xq, w11) + b11
+    with jax.named_scope(last["spec"].name):
+        xq = qx.dequantize()
+        w11 = fxp.CONV11_W.roundtrip(last["w"])
+        b11 = fxp.CONV11_B.roundtrip(last["b"])
+        return _conv2d(xq, w11) + b11
+
+
+def _w1a8_layer(spec: ConvSpec, entry: dict, qx: QTensor, cfg: KernelConfig,
+                s_next: jax.Array) -> QTensor:
+    """One W1A8 layer of `yolo_forward_kernel`: its Pallas kernel, named
+    ``w1a8_<layer>``, on the input codes; ``s_next`` is the step its
+    epilogue quantizes onto."""
+    # Mul_prev = this layer's input steps (= qx.scale: the QTensor
+    # carries exactly the dequant context the next kernel fuses);
+    # per-channel requant is folded into the epilogue:
+    # q = round(acc·(α/s_next) + b/s_next), out_step=1.
+    mul_prev = qx.scale
+    div_eff = entry["alpha"] / s_next
+    b_eff = entry["b"] / s_next
+    name = f"w1a8_{spec.name}"
+    if spec.ksize == 3 and spec.pool:
+        codes = conv_ops.w1a8_conv3x3_pool(
+            qx.data, entry["w_packed"], mul_prev, div_eff, b_eff,
+            cin=spec.cin, config=cfg, name=name)
+    elif spec.ksize == 3:
+        codes = conv_ops.w1a8_conv3x3(
+            qx.data, entry["w_packed"], mul_prev, div_eff, b_eff,
+            cin=spec.cin, config=cfg, name=name)
+    else:
+        b, h, w, _ = qx.data.shape
+        codes = mm_ops.w1a8_matmul(
+            qx.data.reshape(b * h * w, spec.cin), entry["w_packed"],
+            mul_prev, div_eff, b_eff, k=spec.cin, config=cfg,
+            name=name).reshape(b, h, w, spec.cout)
+    return QTensor.from_codes(codes, s_next, axis=-1)

@@ -40,6 +40,7 @@ import numpy as np
 from repro.kernels.config import _UNSET
 from repro.models.layers import ModelConfig
 from repro.serve import cache as cache_mod
+from repro.serve import tracing
 from repro.serve.api import Emission, ServeRequest
 from repro.serve.engine import decode_step, decode_step_donemask, prefill
 
@@ -106,6 +107,11 @@ class DispatchWindow:
 
     def __len__(self) -> int:
         return len(self._q)
+
+    @property
+    def tickets(self) -> int:
+        """Tickets issued so far: the next push takes this one."""
+        return self._tickets
 
     def push(self, item) -> int:
         ticket = self._tickets
@@ -405,14 +411,16 @@ class DetectionBackend:
         self.host_syncs = 0
         self.host_sync_bytes = 0
         self.completion_syncs = 0
+        self.tracer = tracing.NULL                # host spans, when set
 
         def _bundle(imgs):
             raw = yolo.yolo_forward_kernel(art, imgs, profile=profile,
                                            **overrides)
             boxes, scores, classes = detection.postprocess(raw, **self.post)
             if device_nms:                        # compact emission wire only
-                return jax.vmap(detection.compact_detections)(boxes, scores,
-                                                              classes)
+                with jax.named_scope("wire"):
+                    return jax.vmap(detection.compact_detections)(
+                        boxes, scores, classes)
             return raw, boxes, scores, classes
 
         # ONE jit, traced once per bucket shape: the jit cache is the
@@ -449,6 +457,7 @@ class DetectionBackend:
         twin.host_syncs = 0
         twin.host_sync_bytes = 0
         twin.completion_syncs = 0
+        twin.tracer = tracing.NULL
         return twin
 
     def bucket_of(self, req: ServeRequest) -> int:
@@ -492,12 +501,15 @@ class DetectionBackend:
         staged, self._staged = self._staged, {}
         pushed = 0
         for bucket, group in staged.items():
-            imgs = jnp.stack([self._to_float(r.image) for _, r in group])
-            if imgs.shape[0] < self.width:       # fixed-width executable
-                imgs = jnp.pad(imgs, ((0, self.width - imgs.shape[0]),
-                                      (0, 0), (0, 0), (0, 0)))
-            self._window.push(([slot for slot, _ in group],
-                               self._fwd(imgs)))  # async dispatch
+            number = self._window.tickets         # this dispatch's ticket
+            with self.tracer.span("detect.stage", number, n=len(group)):
+                imgs = jnp.stack([self._to_float(r.image) for _, r in group])
+                if imgs.shape[0] < self.width:   # fixed-width executable
+                    imgs = jnp.pad(imgs, ((0, self.width - imgs.shape[0]),
+                                          (0, 0), (0, 0), (0, 0)))
+            with self.tracer.span("detect.dispatch", number):
+                results = self._fwd(imgs)         # async dispatch
+            self._window.push((number, [slot for slot, _ in group], results))
             pushed += 1
             # credit the transfer to the tick that dispatched the batch —
             # the payload width is static, the harvest tick is a schedule
@@ -511,9 +523,15 @@ class DetectionBackend:
             self._emit(inflight)
 
     def _emit(self, inflight: tuple) -> None:
-        slots_, results = inflight
+        number, slots_, results = inflight
+        with self.tracer.span("detect.wait", number):
+            fetched = jax.device_get(results)     # one transfer
+        with self.tracer.span("detect.unpack", number):
+            self._unpack(slots_, fetched)
+
+    def _unpack(self, slots_: list, fetched: tuple) -> None:
         if self.device_nms:
-            boxes, scores, classes, valid = jax.device_get(results)
+            boxes, scores, classes, valid = fetched
             for i, slot in enumerate(slots_):
                 # upcast host-side (lossless); the fp16/int8 forms above are
                 # what crossed the wire and what _batch_bytes counted
@@ -524,7 +542,7 @@ class DetectionBackend:
                 self._emissions.setdefault(slot, []).append(
                     Emission(kind="detections", payload=payload, final=True))
             return
-        raw, boxes, scores, classes = jax.device_get(results)  # one transfer
+        raw, boxes, scores, classes = fetched
         for i, slot in enumerate(slots_):
             payload = {"boxes": np.asarray(boxes[i]),
                        "scores": np.asarray(scores[i]),

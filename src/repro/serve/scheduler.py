@@ -47,6 +47,7 @@ import heapq
 import time
 from typing import Callable, Dict, List, Optional
 
+from repro.serve import tracing
 from repro.serve.api import (Backend, EngineMetrics, ServeRequest,
                              ServeResult)
 
@@ -90,6 +91,10 @@ class Scheduler:
         self._synced = getattr(backend, "host_syncs", 0)
         self._synced_bytes = getattr(backend, "host_sync_bytes", 0)
         self._completion_synced = getattr(backend, "completion_syncs", 0)
+        # host spans (serve/tracing.py); submit times are kept only while
+        # a recorder is set, for the sched.queue spans
+        self.tracer = tracing.NULL
+        self._submitted_at: Dict[int, float] = {}
 
     # -- introspection (the fleet router routes on these) --------------------
     @property
@@ -138,6 +143,8 @@ class Scheduler:
         self._seq += 1
         heapq.heappush(self.queue, (getattr(req, "priority", 0), dl, seq))
         self._waiting[seq] = (req, self.metrics.ticks)
+        if self.tracer.enabled:
+            self._submitted_at[seq] = self.tracer.clock()
         if dl != _NO_DEADLINE:
             heapq.heappush(self._deadlines, (dl, seq))
         return True
@@ -152,6 +159,7 @@ class Scheduler:
             entry = self._waiting.pop(seq, None)
             if entry is None:                      # already admitted
                 continue
+            self._submitted_at.pop(seq, None)
             req, submitted = entry
             self.metrics.expired += 1
             self._emit_result(ServeResult(
@@ -176,6 +184,10 @@ class Scheduler:
         waiting, re-pushed with its original heap key — instead of ending
         the scan, so a starved bucket is never silently blocked behind a
         full sibling bucket (tests/test_serve_kdeep.py regression)."""
+        with self.tracer.span("sched.admit", self.metrics.ticks):
+            return self._admit()
+
+    def _admit(self) -> int:
         self._expire_overdue()
         width = getattr(self.backend, "admit_width", None) \
             or self.backend.capacity
@@ -184,6 +196,7 @@ class Scheduler:
         per_bucket: collections.Counter = collections.Counter()
         deferred: List[tuple] = []
         batch = []
+        waits = []                                 # (submit time, rid)
         while self.queue and self.free and len(batch) < width:
             item = heapq.heappop(self.queue)
             seq = item[2]
@@ -197,6 +210,7 @@ class Scheduler:
                 # completion already impossible (even a 1-tick service
                 # misses): expire from the queue instead of burning a slot
                 del self._waiting[seq]
+                self._submitted_at.pop(seq, None)
                 self.metrics.expired += 1
                 self._emit_result(ServeResult(
                     rid=req.rid, finish_reason="expired",
@@ -210,6 +224,9 @@ class Scheduler:
                     continue                       # siblings keep admitting
                 per_bucket[b] += 1
             del self._waiting[seq]
+            queued = self._submitted_at.pop(seq, None)
+            if queued is not None:
+                waits.append((queued, req.rid))
             slot = self.free.pop(0)
             batch.append((slot, req))
             self.active[slot] = _Active(
@@ -218,18 +235,17 @@ class Scheduler:
                 complete_by=complete_by)
         for item in deferred:                      # original keys: ordering
             heapq.heappush(self.queue, item)       # is stable across ticks
+        if waits and self.tracer.enabled:
+            now = self.tracer.clock()
+            for queued, rid in waits:
+                self.tracer.add("sched.queue", queued, now, rid)
         if batch:
             self.backend.admit(batch)
         return len(batch)
 
-    def step_harvest(self, t0: Optional[float] = None) -> None:
-        """One backend compute tick + emission ingest / completion. ``t0``
-        lets tick() charge admission (batched prefill) to this tick's
-        latency — EXPERIMENTS.md §Serve numbers are end-to-end."""
-        if t0 is None:
-            t0 = time.perf_counter()
-        active_now = len(self.active)
-        self.backend.step()
+    def _ingest(self) -> tuple:
+        """Ingest the backend's emissions in order, finishing requests and
+        dropping overruns; returns (tokens, images) emitted."""
         tokens = images = 0
         for slot, ems in sorted(self.backend.harvest().items()):
             rec = self.active.get(slot)
@@ -270,6 +286,18 @@ class Scheduler:
                    if self.metrics.ticks >= rec.complete_by]
         for slot in overrun:
             self._drop_inflight(slot)
+        return tokens, images
+
+    def step_harvest(self, t0: Optional[float] = None) -> None:
+        """One backend compute tick + emission ingest / completion. ``t0``
+        lets tick() charge admission (batched prefill) to this tick's
+        latency — EXPERIMENTS.md §Serve numbers are end-to-end."""
+        if t0 is None:
+            t0 = time.perf_counter()
+        active_now = len(self.active)
+        self.backend.step()
+        with self.tracer.span("sched.harvest", self.metrics.ticks):
+            tokens, images = self._ingest()
         # credit this tick's blocking device→host transfers (backends keep
         # running counters; the scheduler snapshots the step-path delta)
         syncs = getattr(self.backend, "host_syncs", None)
@@ -290,8 +318,9 @@ class Scheduler:
 
     def tick(self) -> None:
         t0 = time.perf_counter()
-        self.admit()
-        self.step_harvest(t0=t0)
+        with self.tracer.span("sched.tick", self.metrics.ticks):
+            self.admit()
+            self.step_harvest(t0=t0)
 
     # -- driving -------------------------------------------------------------
     def run(self, requests=None) -> List[ServeResult]:

@@ -1,0 +1,110 @@
+"""Host spans of the detector server (serve/tracing.py): a few width-4
+batches at 64x64 through Scheduler over DetectionBackend, kernels in
+interpret mode."""
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.models import yolo
+from repro.serve import DetectionBackend, Scheduler, ServeRequest, tracing
+
+SIZE, WIDTH, FRAMES = 64, 4, 10          # dispatches of 4, 4 and 2 frames
+
+
+@pytest.fixture(scope="module")
+def art():
+    rng = np.random.default_rng(0)
+    calib = jnp.asarray(rng.integers(0, 256, (1, SIZE, SIZE, 3), np.uint8),
+                        jnp.float32) / 256.0
+    _, art = yolo.build_detector(jax.random.PRNGKey(3), calib)
+    return art
+
+
+def serve(art, recorder=None):
+    """Serve FRAMES frames at depth 2; returns (scheduler, results)."""
+    backend = DetectionBackend(art, slots=WIDTH, depth=2,
+                               profile="interpret", device_nms=True)
+    sched = Scheduler(backend)
+    if recorder is not None:
+        sched.tracer = backend.tracer = recorder
+    rng = np.random.default_rng(1)
+    reqs = [ServeRequest(rid=100 + i, image=rng.integers(
+        0, 256, (SIZE, SIZE, 3), np.uint8)) for i in range(FRAMES)]
+    return sched, sched.run(reqs)
+
+
+@pytest.fixture(scope="module")
+def traced(art):
+    rec = tracing.Recorder()
+    sched, results = serve(art, rec)
+    return rec, sched, results
+
+
+def by_name(rec) -> dict:
+    out = {}
+    for name, start, dur, key, attrs in rec.items:
+        out.setdefault(name, []).append((start, dur, key, attrs))
+    return out
+
+
+def test_null_recorder_records_nothing_and_reads_no_clock(art, monkeypatch):
+    calls = []
+    fake = types.SimpleNamespace(
+        perf_counter=lambda: calls.append(1) or float(len(calls)))
+    monkeypatch.setattr(tracing, "time", fake)
+    sched, results = serve(art)
+    assert len(results) == FRAMES
+    assert sched.tracer is tracing.NULL
+    assert sched.backend.tracer is tracing.NULL
+    assert list(tracing.NULL.items) == [] and not sched._submitted_at
+    assert calls == []
+    # the same patch is seen by a recorder: the null one made no call
+    rec = tracing.Recorder()
+    with rec.span("x", 1):
+        pass
+    assert len(calls) == 2 and rec.items == [("x", 1.0, 1.0, 1, {})]
+
+
+def test_every_dispatch_has_its_spans_inside_a_tick(traced):
+    rec, sched, results = traced
+    assert sorted(r.rid for r in results) == [100 + i for i in range(FRAMES)]
+    spans = by_name(rec)
+    ticks = spans["sched.tick"]
+    assert [k for _, _, k, _ in ticks] == list(range(len(ticks)))
+    stage = {k: a["n"] for _, _, k, a in spans["detect.stage"]}
+    assert stage == {0: 4, 1: 4, 2: 2}
+    for name in ("detect.stage", "detect.dispatch", "detect.wait",
+                 "detect.unpack"):
+        keys = sorted(k for _, _, k, _ in spans[name])
+        assert keys == [0, 1, 2], name
+        for start, dur, _, _ in spans[name]:
+            assert dur >= 0
+            assert any(s <= start and start + dur <= s + d
+                       for s, d, _, _ in ticks), name
+    # spans of one dispatch in order: staged, enqueued, then fetched
+    first = {name: {k: s for s, _, k, _ in spans[name]}
+             for name in ("detect.stage", "detect.dispatch", "detect.wait")}
+    for k in range(3):
+        assert first["detect.stage"][k] <= first["detect.dispatch"][k] \
+            <= first["detect.wait"][k]
+    for name in ("sched.admit", "sched.harvest"):
+        assert sorted(k for _, _, k, _ in spans[name]) == \
+            [k for _, _, k, _ in ticks]
+
+
+def test_every_request_has_one_queue_span(traced):
+    rec, _, results = traced
+    queue = by_name(rec)["sched.queue"]
+    assert sorted(k for _, _, k, _ in queue) == sorted(r.rid for r in results)
+    assert all(d >= 0 for _, d, _, _ in queue)
+    # the last two frames wait for a free slot across ticks
+    assert max(d for _, d, _, _ in queue) > min(d for _, d, _, _ in queue)
+
+
+def test_spawn_gives_a_replica_the_null_recorder(art):
+    backend = DetectionBackend(art, slots=WIDTH, profile="interpret")
+    backend.tracer = tracing.Recorder()
+    assert backend.spawn().tracer is tracing.NULL

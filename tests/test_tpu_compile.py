@@ -97,23 +97,43 @@ def test_w1a8_kernel_compiles_for_v5e(name, op, dims, accum, one_chip,
     assert _custom_calls(compiled) == 1, (name, op, accum)
 
 
-def test_serve_bundle_compiles_for_v5e(topo, one_chip, no_persistent_cache,
-                                       monkeypatch):
+@pytest.fixture(scope="module")
+def serve_bundle(topo, one_chip, no_persistent_cache):
     """The DetectionBackend bundle (Pallas convs + head + device NMS) at
-    320 x 32 slots, as `chip_smoke.py` serves it: one Pallas kernel per
-    W1A8 layer. The test steers the configs to what a v5e resolves:
-    the chip's autotune key, and compiled (not interpreted) kernels."""
+    320 x 32 slots, as `chip_smoke.py` serves it, compiled for one chip.
+    The configs are steered to what a v5e resolves: the chip's autotune
+    key, and compiled (not interpreted) kernels."""
     from repro.serve import DetectionBackend
     kind = topo.devices[0].device_kind
-    monkeypatch.setattr(kcfg, "device_key",
-                        lambda: kind.strip().lower().replace(" ", "-"))
-    monkeypatch.setattr(KernelConfig, "resolved_interpret",
-                        lambda self: bool(self.interpret))
-    rng = np.random.default_rng(0)
-    calib = jnp.asarray(rng.integers(0, 256, (1, 320, 320, 3), np.uint8),
-                        jnp.float32) / 256
-    _, art = yolo.build_detector(jax.random.PRNGKey(0), calib)
-    backend = DetectionBackend(art, slots=SERVE_SLOTS, depth=2,
-                               profile="tuned", device_nms=True)
-    compiled = backend.lower(320, sharding=one_chip).compile()
-    assert _custom_calls(compiled) >= N_W1A8_LAYERS
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kcfg, "device_key",
+                   lambda: kind.strip().lower().replace(" ", "-"))
+        mp.setattr(KernelConfig, "resolved_interpret",
+                   lambda self: bool(self.interpret))
+        rng = np.random.default_rng(0)
+        calib = jnp.asarray(rng.integers(0, 256, (1, 320, 320, 3), np.uint8),
+                            jnp.float32) / 256
+        _, art = yolo.build_detector(jax.random.PRNGKey(0), calib)
+        backend = DetectionBackend(art, slots=SERVE_SLOTS, depth=2,
+                                   profile="tuned", device_nms=True)
+        return backend.lower(320, sharding=one_chip).compile()
+
+
+def test_serve_bundle_compiles_for_v5e(serve_bundle):
+    """One Pallas kernel per W1A8 layer."""
+    assert _custom_calls(serve_bundle) >= N_W1A8_LAYERS
+
+
+def test_serve_bundle_names_kernels_and_layers(serve_bundle):
+    """Each custom call is named after its layer (``w1a8_conv2`` ..
+    ``w1a8_conv10``), and the layers' named scopes reach the compiled
+    HLO's ``op_name`` metadata, where a trace's ops are mapped to layers."""
+    text = serve_bundle.as_text()
+    calls = [line.split(" = ", 1)[0].strip().lstrip("%").rsplit(".", 1)[0]
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(calls) == sorted(f"w1a8_conv{i}" for i in range(2, 11))
+    for scope in ("conv1", "conv11", "decode", "nms", "wire"):
+        assert f'op_name="jit(_bundle)/{scope}/' in text \
+            or f'op_name="jit(_bundle)/jit(postprocess)/{scope}/' in text, \
+            scope
