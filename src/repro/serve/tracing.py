@@ -14,8 +14,8 @@ Spans of one tick:
 - ``sched.admit`` (tick number): expiry, heap pops, the batched admit;
 - ``sched.queue`` (request id): one per admitted request, from `submit`
   to its admission;
-- ``detect.stage`` (dispatch number, ``n`` real frames): per-frame
-  conversion and upload, stack, pad;
+- ``detect.stage`` (dispatch number, ``n`` real frames, ``bytes`` sent to
+  the device): host stack, one upload, pad;
 - ``detect.dispatch`` (dispatch number): the bundle's async enqueue;
 - ``detect.wait`` (dispatch number of the batch fetched): the host
   blocked in ``jax.device_get``;
