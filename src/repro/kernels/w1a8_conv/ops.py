@@ -8,6 +8,14 @@ round-trips through HBM, which is what lets the streaming serving path
 Bit-exact vs conv-then-reduce_window (same per-row dot shapes, same
 rounding, max commutes with the uint8 cast).
 
+`w1a8_conv3x3_gemm` — a 3×3 conv at stride 1 or 2, with an optional
+residual input fused into the epilogue, as an im2col view of the uint8
+codes through the tiled W1A8 matmul kernel. The line-buffer kernels above
+unpack a layer's whole sign matrix in every grid step and hold one image
+row per step; the matmul kernel tiles K and N, so this route holds the
+wide layers (9·Cin up to 9216, Cout up to 1024) and computes a stride-2
+conv at its output pixels only.
+
 Launch configuration (accum mode, row blocking, interpret, fused-vs-split
 pool routing) comes from a `KernelConfig` (``config=``); the old per-call
 kwargs survive one release behind a DeprecationWarning.
@@ -26,6 +34,7 @@ from repro.kernels import config as _cfg
 from repro.kernels.config import KernelConfig, _UNSET
 from repro.kernels.w1a8_conv import kernel as _k
 from repro.kernels.w1a8_conv import ref as _ref
+from repro.kernels.w1a8_matmul import ops as _mm
 
 
 def conv_pack_weights(w: jax.Array) -> jax.Array:
@@ -153,3 +162,27 @@ def _w1a8_conv3x3_pool(a_u8, w_packed, mul_prev, div_post, bias, *,
                               cin=cin, out_step=out_step, accum=config.accum,
                               rows=config.conv_rows(a_u8.shape[1] // 2),
                               interpret=config.interpret, name=name)
+
+
+def w1a8_conv3x3_gemm(a_u8: jax.Array, w_packed: jax.Array,
+                      mul_prev: jax.Array, div_post: jax.Array,
+                      bias: jax.Array, *, cin: int, stride: int = 1,
+                      skip: Optional[jax.Array] = None,
+                      skip_ratio: Optional[jax.Array] = None,
+                      config: KernelConfig,
+                      name: Optional[str] = None) -> jax.Array:
+    """3×3 conv, one pixel of zero padding a side, at ``stride`` 1 or 2:
+    a_u8 (B,H,W,Cin) uint8 → (B,Ho,Wo,Cout), Ho = (H-1)//stride + 1.
+
+    The (B·Ho·Wo, 9·Cin) im2col view of the codes, in the kernel's
+    (dy, dx, cin) order, goes through `w1a8_matmul` with ``config`` (op
+    "matmul") against the same packed signs as `conv_pack_weights` makes;
+    Mul_prev repeats per tap. ``skip``/``skip_ratio`` are the residual
+    input of `w1a8_matmul`. Same contract as `_ref.w1a8_conv3x3_ref`.
+    """
+    if stride not in (1, 2):
+        raise ValueError(f"stride must be 1 or 2, got {stride}")
+    cols = _ref.im2col_3x3(a_u8, stride)
+    return _mm.w1a8_matmul(cols, w_packed, jnp.tile(mul_prev, 9), div_post,
+                           bias, k=9 * cin, config=config, skip=skip,
+                           skip_ratio=skip_ratio, name=name)

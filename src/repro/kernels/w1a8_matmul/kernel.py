@@ -8,7 +8,9 @@ TPU adaptation of the paper's binary PE (§5.2):
     the MXU contraction — Eq. 3-4's "compensation during accumulation",
   * ``Div_current``/bias/round/clip run in the **epilogue** on the final
     K-step, optionally emitting uint8 codes for the next layer (the paper's
-    Post-process module, fused).
+    Post-process module, fused). A residual input (uint8 codes of the
+    block's input, with their step over the output step) can be added
+    there too, after the ReLU, as darknet's shortcut adds it.
 
 Grid: (M/bm, N/bn, K/bk), K innermost ("arbitrary"); f32 accumulation in a
 VMEM scratch tile. MXU operands are bf16 (entries |m·a| ≤ 255·m exactly
@@ -103,9 +105,34 @@ def _xnor_accumulate(a_i32: jax.Array, wp_tile: jax.Array,
     return acc
 
 
-def _matmul_kernel(a_ref, wp_ref, m_ref, d_ref, b_ref, o_ref, acc_ref, *,
-                   nk: int, bk: int, bn: int, out_step: Optional[float],
-                   compute_dtype):
+def _epilogue(acc, d_ref, b_ref, skip, o_ref, out_step: Optional[float]):
+    """Div_current and bias, then, for a residual block's last conv, the
+    shortcut: ReLU on the conv, plus the block input's codes times their
+    step over the output step (``skip`` = (codes ref, ratio ref)); then
+    round/clip to codes when ``out_step`` is given."""
+    y = acc * d_ref[...].astype(jnp.float32) + b_ref[...].astype(jnp.float32)
+    if skip is not None:
+        s_ref, r_ref = skip
+        y = (jnp.maximum(y, 0.0)
+             + s_ref[...].astype(jnp.int32).astype(jnp.float32)
+             * r_ref[...].astype(jnp.float32))
+    if out_step is None:
+        o_ref[...] = y.astype(o_ref.dtype)
+    else:
+        o_ref[...] = requant_epilogue(y, out_step, o_ref.dtype)
+
+
+def _split_skip(refs, has_skip: bool):
+    """(skip codes ref, ratio ref) or None, the output ref, the scratch."""
+    if has_skip:
+        return (refs[0], refs[1]), refs[2], refs[3]
+    return None, refs[0], refs[1]
+
+
+def _matmul_kernel(a_ref, wp_ref, m_ref, d_ref, b_ref, *refs, nk: int,
+                   bk: int, bn: int, out_step: Optional[float],
+                   compute_dtype, has_skip: bool):
+    skip, o_ref, acc_ref = _split_skip(refs, has_skip)
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
@@ -118,19 +145,16 @@ def _matmul_kernel(a_ref, wp_ref, m_ref, d_ref, b_ref, o_ref, acc_ref, *,
     signs = _unpack_tile(wp_ref[...], bk, bn, compute_dtype)
     acc_ref[...] += jnp.dot(am, signs, preferred_element_type=jnp.float32)
 
-    # Epilogue on the last K step: Div_current, bias, (round, clip).
+    # Epilogue on the last K step: Div_current, bias, (shortcut), (round,
+    # clip).
     @pl.when(kk == nk - 1)
-    def _epilogue():
-        y = acc_ref[...] * d_ref[...].astype(jnp.float32) \
-            + b_ref[...].astype(jnp.float32)
-        if out_step is None:
-            o_ref[...] = y.astype(o_ref.dtype)
-        else:
-            o_ref[...] = requant_epilogue(y, out_step, o_ref.dtype)
+    def _fin():
+        _epilogue(acc_ref[...], d_ref, b_ref, skip, o_ref, out_step)
 
 
-def _popcount_matmul_kernel(a_ref, wp_ref, d_ref, b_ref, o_ref, acc_ref, *,
-                            nk: int, bk: int, out_step: Optional[float]):
+def _popcount_matmul_kernel(a_ref, wp_ref, d_ref, b_ref, *refs, nk: int,
+                            bk: int, out_step: Optional[float],
+                            has_skip: bool):
     """XNOR-popcount accumulation (uniform-Mul_prev contract).
 
     No per-input-channel prologue is possible once the activations are bit
@@ -138,6 +162,7 @@ def _popcount_matmul_kernel(a_ref, wp_ref, d_ref, b_ref, o_ref, acc_ref, *,
     scalar into Div_current so the epilogue expression — and hence the
     rounding — is identical to the dot path's.
     """
+    skip, o_ref, acc_ref = _split_skip(refs, has_skip)
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
@@ -148,18 +173,26 @@ def _popcount_matmul_kernel(a_ref, wp_ref, d_ref, b_ref, o_ref, acc_ref, *,
                                      wp_ref[...], bk)
 
     @pl.when(kk == nk - 1)
-    def _epilogue():
-        y = acc_ref[...].astype(jnp.float32) * d_ref[...].astype(jnp.float32) \
-            + b_ref[...].astype(jnp.float32)
-        if out_step is None:
-            o_ref[...] = y.astype(o_ref.dtype)
-        else:
-            o_ref[...] = requant_epilogue(y, out_step, o_ref.dtype)
+    def _fin():
+        _epilogue(acc_ref[...].astype(jnp.float32), d_ref, b_ref, skip,
+                  o_ref, out_step)
+
+
+def _skip_operands(skip, skip_ratio, bm: int, bn: int) -> tuple:
+    """In-specs and operands of the residual input: (M, N) uint8 codes
+    tiled like the output, and its (1, N) step ratio."""
+    if skip is None:
+        return [], ()
+    return ([pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
+             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j))],
+            (skip, skip_ratio))
 
 
 def w1a8_matmul_popcount_pallas(a_u8: jax.Array, w_packed: jax.Array,
                                 div_post: jax.Array, bias: jax.Array, *,
                                 out_step: Optional[float] = None,
+                                skip: Optional[jax.Array] = None,
+                                skip_ratio: Optional[jax.Array] = None,
                                 bm: int = DEF_BM, bk: int = DEF_BK,
                                 bn: int = DEF_BN,
                                 interpret: bool = False,
@@ -170,8 +203,9 @@ def w1a8_matmul_popcount_pallas(a_u8: jax.Array, w_packed: jax.Array,
     n = w_packed.shape[1]
     assert k % bk == 0 and m % bm == 0 and n % bn == 0 and bk % PACK == 0
     nk = k // bk
+    skip_specs, skip_ops = _skip_operands(skip, skip_ratio, bm, bn)
     kernel = functools.partial(_popcount_matmul_kernel, nk=nk, bk=bk,
-                               out_step=out_step)
+                               out_step=out_step, has_skip=skip is not None)
     out_dtype = jnp.float32 if out_step is None else jnp.uint8
     return pl.pallas_call(
         kernel,
@@ -181,7 +215,7 @@ def w1a8_matmul_popcount_pallas(a_u8: jax.Array, w_packed: jax.Array,
             pl.BlockSpec((bk // PACK, bn), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
-        ],
+        ] + skip_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
@@ -189,13 +223,15 @@ def w1a8_matmul_popcount_pallas(a_u8: jax.Array, w_packed: jax.Array,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(a_u8, w_packed, div_post, bias)
+    )(a_u8, w_packed, div_post, bias, *skip_ops)
 
 
 def w1a8_matmul_pallas(a_u8: jax.Array, w_packed: jax.Array,
                        mul_prev: jax.Array, div_post: jax.Array,
                        bias: jax.Array, *,
                        out_step: Optional[float] = None,
+                       skip: Optional[jax.Array] = None,
+                       skip_ratio: Optional[jax.Array] = None,
                        bm: int = DEF_BM, bk: int = DEF_BK, bn: int = DEF_BN,
                        compute_dtype=jnp.bfloat16,
                        interpret: bool = False,
@@ -203,14 +239,18 @@ def w1a8_matmul_pallas(a_u8: jax.Array, w_packed: jax.Array,
     """Shapes (pre-padded to tile multiples by ops.py):
     a_u8 (M, K) uint8 · w_packed (K/32, N) uint32 · mul_prev (1, K) f32 ·
     div_post/bias (1, N) f32 → (M, N) f32, or uint8 codes when out_step given.
+    ``skip`` (M, N) uint8 codes and ``skip_ratio`` (1, N) f32 add a residual
+    input in the epilogue (see `_epilogue`).
     """
     m, k = a_u8.shape
     n = w_packed.shape[1]
     assert k % bk == 0 and m % bm == 0 and n % bn == 0 and bk % PACK == 0
     nk = k // bk
     grid = (m // bm, n // bn, nk)
+    skip_specs, skip_ops = _skip_operands(skip, skip_ratio, bm, bn)
     kernel = functools.partial(_matmul_kernel, nk=nk, bk=bk, bn=bn,
-                               out_step=out_step, compute_dtype=compute_dtype)
+                               out_step=out_step, compute_dtype=compute_dtype,
+                               has_skip=skip is not None)
     out_dtype = jnp.float32 if out_step is None else jnp.uint8
     return pl.pallas_call(
         kernel,
@@ -221,7 +261,7 @@ def w1a8_matmul_pallas(a_u8: jax.Array, w_packed: jax.Array,
             pl.BlockSpec((1, bk), lambda i, j, kk: (0, kk)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
-        ],
+        ] + skip_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
@@ -229,7 +269,7 @@ def w1a8_matmul_pallas(a_u8: jax.Array, w_packed: jax.Array,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(a_u8, w_packed, mul_prev, div_post, bias)
+    )(a_u8, w_packed, mul_prev, div_post, bias, *skip_ops)
 
 
 # ---------------------------------------------------------------------------

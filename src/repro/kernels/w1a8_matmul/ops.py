@@ -24,9 +24,17 @@ from repro.kernels.w1a8_matmul import ref as _ref
 def w1a8_matmul(a_u8: jax.Array, w_packed: jax.Array, mul_prev: jax.Array,
                 div_post: jax.Array, bias: jax.Array, *, k: int,
                 config: Optional[KernelConfig] = None,
+                skip: Optional[jax.Array] = None,
+                skip_ratio: Optional[jax.Array] = None,
                 out_step=_UNSET, accum=_UNSET, interpret=_UNSET,
                 use_kernel=_UNSET, name: Optional[str] = None) -> jax.Array:
     """y = ((a ⊙ mul_prev) @ unpack(w_packed)) ⊙ div_post + bias  [+ requant].
+
+    With ``skip`` ((..., N) uint8 codes of a residual block's input) and
+    ``skip_ratio`` ((N,) f32, their step over the output step) the epilogue
+    adds the shortcut after the ReLU, as darknet does:
+    y = max(y, 0) + skip ⊙ skip_ratio, then the requant (config.out_step
+    must be set).
 
     a_u8: (..., K) uint8 codes; w_packed: (ceil(K/32), N) uint32;
     mul_prev: (K,) f32; div_post, bias: (N,) f32.
@@ -43,18 +51,22 @@ def w1a8_matmul(a_u8: jax.Array, w_packed: jax.Array, mul_prev: jax.Array,
     cfg = _cfg.normalize("matmul", config, out_step=out_step, accum=accum,
                          interpret=interpret, use_kernel=use_kernel)
     cfg = cfg.replace(interpret=cfg.resolved_interpret())
-    return _w1a8_matmul(a_u8, w_packed, mul_prev, div_post, bias,
-                        k=k, config=cfg, name=name)
+    if skip is not None and cfg.out_step is None:
+        raise ValueError("a residual input needs a quantizing epilogue "
+                         "(config.out_step)")
+    return _w1a8_matmul(a_u8, w_packed, mul_prev, div_post, bias, skip,
+                        skip_ratio, k=k, config=cfg, name=name)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "config", "name"))
-def _w1a8_matmul(a_u8, w_packed, mul_prev, div_post, bias, *, k: int,
-                 config: KernelConfig, name: Optional[str] = None
-                 ) -> jax.Array:
+def _w1a8_matmul(a_u8, w_packed, mul_prev, div_post, bias, skip=None,
+                 skip_ratio=None, *, k: int, config: KernelConfig,
+                 name: Optional[str] = None) -> jax.Array:
     out_step = config.out_step
     if not config.use_kernel:
         y = _ref.w1a8_matmul_ref(a_u8, w_packed, k, mul_prev, div_post, bias,
-                                 None if out_step is None else jnp.float32(out_step))
+                                 None if out_step is None else jnp.float32(out_step),
+                                 skip=skip, skip_ratio=skip_ratio)
         return y
 
     lead = a_u8.shape[:-1]
@@ -74,6 +86,11 @@ def _w1a8_matmul(a_u8, w_packed, mul_prev, div_post, bias, *, k: int,
         wp = jnp.pad(wp, ((0, kp // PACK - wp.shape[0]), (0, np_ - n)))
     dv = jnp.pad(div_post.astype(jnp.float32), (0, np_ - n)).reshape(1, np_)
     bs = jnp.pad(bias.astype(jnp.float32), (0, np_ - n)).reshape(1, np_)
+    res = {}
+    if skip is not None:
+        res = {"skip": jnp.pad(skip.reshape(m, n), ((0, mp - m), (0, np_ - n))),
+               "skip_ratio": jnp.pad(skip_ratio.astype(jnp.float32),
+                                     (0, np_ - n)).reshape(1, np_)}
 
     if config.accum == "popcount":
         # zero-padded K lanes carry zero codes (ratio 0 · zero pad) and
@@ -84,11 +101,12 @@ def _w1a8_matmul(a_u8, w_packed, mul_prev, div_post, bias, *, k: int,
         y = _k.w1a8_matmul_popcount_pallas(a2, wp, dv, bs, out_step=out_step,
                                            bm=bm, bk=bk, bn=bn,
                                            interpret=config.interpret,
-                                           name=name)
+                                           name=name, **res)
     else:
         y = _k.w1a8_matmul_pallas(a2, wp, mul, dv, bs, out_step=out_step,
                                   bm=bm, bk=bk, bn=bn,
-                                  interpret=config.interpret, name=name)
+                                  interpret=config.interpret, name=name,
+                                  **res)
     return y[:m, :n].reshape(lead + (n,))
 
 

@@ -1,41 +1,56 @@
 """Detection-head decode + NMS (paper §6.2 post-processing, the "PS side").
 
-The head emits (B, 10, 10, 75) raw values = 3 anchors × (tx, ty, tw, th,
-obj, 20 cls) per cell, y/x/channel order. Decode follows YOLOv3:
+A head emits (B, G, G, A·(5 + C)) raw values = A anchors × (tx, ty, tw, th,
+obj, C cls) per cell, y/x/channel order (the paper's: 10×10×75, 3 anchors,
+20 VOC classes). Decode follows YOLOv3:
   bx = (σ(tx) + cx)/G, by = (σ(ty) + cy)/G, bw = pw·e^tw, bh = ph·e^th,
-confidence = σ(obj)·max σ(cls). NMS is class-wise greedy IoU suppression,
-implemented with a fixed-iteration lax.fori_loop (jit-safe, static shapes).
+confidence = σ(obj)·max σ(cls). A model with several heads (YOLOv3's 13,
+26 and 52 grids) decodes each with its own anchors into one candidate
+list, heads in graph order, each head's cells row-major with its anchors
+innermost. NMS is class-wise greedy IoU suppression, implemented with a
+fixed-iteration lax.fori_loop (jit-safe, static shapes).
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.yolo import NUM_ANCHORS, NUM_CLASSES
-
-# Anchor priors (fraction of image size), 3 anchors for the single 10×10 head.
-ANCHORS = jnp.asarray([[0.12, 0.18], [0.32, 0.42], [0.72, 0.78]], jnp.float32)
+from repro.models import yolo
 
 
-def decode_head(raw: jax.Array) -> dict:
-    """raw (B, G, G, 75) → boxes (B, G·G·A, 4) cxcywh in [0,1], scores, cls.
+def decode_head(raw: jax.Array, anchors=yolo.ANCHORS) -> dict:
+    """raw (B, G, G, A·(5+C)) → boxes (B, G·G·A, 4) cxcywh in [0,1] and
+    class scores (B, G·G·A, C); ``anchors`` ((w, h), ...) per anchor.
 
     G is read off the raw head (10 for the deployment 320×320 input; a
     resolution bucket of side S decodes a G = S/32 grid) — box coordinates
     stay image-relative fractions, so every bucket shares one decode."""
     b, grid = raw.shape[0], raw.shape[1]
-    r = raw.reshape(b, grid, grid, NUM_ANCHORS, 5 + NUM_CLASSES)
+    na = len(anchors)
+    nc = raw.shape[-1] // na - 5
+    anchors = jnp.asarray(anchors, jnp.float32)
+    r = raw.reshape(b, grid, grid, na, 5 + nc)
     cy, cx = jnp.meshgrid(jnp.arange(grid, dtype=jnp.float32),
                           jnp.arange(grid, dtype=jnp.float32), indexing="ij")
     bx = (jax.nn.sigmoid(r[..., 0]) + cx[None, :, :, None]) / grid
     by = (jax.nn.sigmoid(r[..., 1]) + cy[None, :, :, None]) / grid
-    bw = ANCHORS[None, None, None, :, 0] * jnp.exp(jnp.clip(r[..., 2], -8, 8))
-    bh = ANCHORS[None, None, None, :, 1] * jnp.exp(jnp.clip(r[..., 3], -8, 8))
+    bw = anchors[None, None, None, :, 0] * jnp.exp(jnp.clip(r[..., 2], -8, 8))
+    bh = anchors[None, None, None, :, 1] * jnp.exp(jnp.clip(r[..., 3], -8, 8))
     obj = jax.nn.sigmoid(r[..., 4])
     cls_prob = jax.nn.sigmoid(r[..., 5:])
     boxes = jnp.stack([bx, by, bw, bh], axis=-1).reshape(b, -1, 4)
-    scores = (obj[..., None] * cls_prob).reshape(b, -1, NUM_CLASSES)
+    scores = (obj[..., None] * cls_prob).reshape(b, -1, nc)
     return {"boxes": boxes, "scores": scores}
+
+
+def decode_heads(raws, head_anchors) -> dict:
+    """Several heads' raw outputs → one candidate list (`decode_head` per
+    head with its anchors, concatenated in order)."""
+    dec = [decode_head(r, a) for r, a in zip(raws, head_anchors)]
+    return {k: jnp.concatenate([d[k] for d in dec], axis=1)
+            for k in ("boxes", "scores")}
 
 
 def iou_cxcywh(a: jax.Array, b: jax.Array) -> jax.Array:
@@ -82,17 +97,20 @@ def nms(boxes: jax.Array, scores: jax.Array, *, iou_thresh: float = 0.45,
     return ob, os_, oc
 
 
-import functools
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("iou_thresh", "score_thresh", "max_out"))
-def postprocess(raw: jax.Array, *, iou_thresh: float = 0.45,
+@functools.partial(jax.jit, static_argnames=(
+    "anchors", "iou_thresh", "score_thresh", "max_out"))
+def postprocess(raw, *, anchors=None, iou_thresh: float = 0.45,
                 score_thresh: float = 0.25, max_out: int = 50):
     """Full post-processing for a batch of raw heads (named scopes
-    ``decode`` and ``nms``)."""
+    ``decode`` and ``nms``): ``raw`` is one head's output, with the
+    paper's anchors unless ``anchors`` (a tuple of one ``((w, h), ...)``
+    per head) says otherwise, or a tuple of heads with their ``anchors``.
+    NMS runs over every head's candidates together."""
+    heads = tuple(raw) if isinstance(raw, (tuple, list)) else (raw,)
+    if anchors is None:
+        anchors = (yolo.ANCHORS,)
     with jax.named_scope("decode"):
-        dec = decode_head(raw)
+        dec = decode_heads(heads, anchors)
     with jax.named_scope("nms"):
         return jax.vmap(lambda b, s: nms(b, s, iou_thresh=iou_thresh,
                                          score_thresh=score_thresh,

@@ -311,12 +311,14 @@ class LMBackend:
 class DetectionBackend:
     """Packed-W1A8 YOLO detection backend (one image per request).
 
-    ``art`` is a `models.yolo.deploy_yolo_kernel` artifact; images are
-    (S, S, 3) float in [0, 1] or uint8 raw pixels (divided by 256, the
+    ``art`` is a `models.yolo.deploy_yolo_kernel` artifact of any detector
+    graph (``art["graph"]``; the paper's model where it has none); images
+    are (S, S, 3) float in [0, 1] or uint8 raw pixels (divided by 256, the
     Q0.8 convention), where S is one of the configured resolution
-    ``buckets`` (default: the artifact's buckets, else 320). Emissions
-    carry NMS'd detections plus the raw head for verification against the
-    float reference (core.verify).
+    ``buckets`` (default: the artifact's buckets, else the graph's input
+    side). Emissions carry NMS'd detections plus the raw head (a tuple of
+    heads for a graph with several) for verification against the float
+    reference (core.verify).
 
     The forward (Pallas convs → head decode → NMS) is ONE jitted dispatch
     at a fixed batch width (= ``slots``) **per bucket** — all buckets share
@@ -391,13 +393,15 @@ class DetectionBackend:
         if profile not in yolo.PROFILES:
             raise ValueError(
                 f"profile must be one of {yolo.PROFILES}, got {profile!r}")
+        graph = yolo.art_graph(art)
         if buckets is None:
-            buckets = art.get("buckets") or (yolo.INPUT_SIZE,)
+            buckets = art.get("buckets") or (graph.input_size,)
         self.buckets = tuple(dict.fromkeys(int(b) for b in buckets))
         for b in self.buckets:
             if b <= 0 or b % 32:
                 raise ValueError(f"bucket sizes must be positive multiples "
                                  f"of 32 (5 pools), got {b}")
+            yolo.node_sides(graph, b)
         self.art = art
         self.width = slots                        # device batch per dispatch
         self.depth = depth                        # K-deep dispatch window
@@ -425,9 +429,11 @@ class DetectionBackend:
             # power-of-two scale), so the head matches host-side conversion
             if imgs.dtype == jnp.uint8:
                 imgs = imgs.astype(jnp.float32) / 256.0
-            raw = yolo.yolo_forward_kernel(art, imgs, profile=profile,
-                                           **overrides)
-            boxes, scores, classes = detection.postprocess(raw, **self.post)
+            heads = yolo.graph_forward_kernel(art, imgs, profile=profile,
+                                              **overrides)
+            raw = heads[0] if len(heads) == 1 else heads
+            boxes, scores, classes = detection.postprocess(
+                heads, anchors=graph.head_anchors(), **self.post)
             if device_nms:                        # compact emission wire only
                 with jax.named_scope("wire"):
                     return jax.vmap(detection.compact_detections)(
@@ -568,7 +574,9 @@ class DetectionBackend:
             payload = {"boxes": np.asarray(boxes[i]),
                        "scores": np.asarray(scores[i]),
                        "classes": np.asarray(classes[i]),
-                       "raw": np.asarray(raw[i])}
+                       "raw": (tuple(np.asarray(r[i]) for r in raw)
+                               if isinstance(raw, tuple)
+                               else np.asarray(raw[i]))}
             self._emissions.setdefault(slot, []).append(
                 Emission(kind="raw_head", payload=payload, final=True))
 

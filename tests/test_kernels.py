@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.core import packing
+from repro.kernels.config import KernelConfig
 from repro.kernels.w1a8_conv import ops as conv_ops
 from repro.kernels.w1a8_conv import ref as conv_ref
 from repro.kernels.w1a8_matmul import kernel as mm_kernel
@@ -326,3 +327,78 @@ def test_fused_conv_pool_matches_unfused():
     assert got.shape == (b, h // 2, w // 2, cout)
     diff = np.abs(np.asarray(got, np.int32) - np.asarray(want, np.int32))
     assert (diff <= 1).mean() > 0.995 and diff.max() <= 2
+
+
+def _exact_epilogue_case(cout, seed):
+    """Operands that make the epilogue exact in f32 (a power-of-two Div,
+    half-integer biases, a power-of-two shortcut ratio), so that the
+    kernel and the oracle must agree bit for bit in either accum mode."""
+    div = jnp.full((cout,), 0.125, jnp.float32)
+    bias = (jnp.arange(cout, dtype=jnp.float32) - cout / 2) * 3.0 + 0.5
+    ratio = jnp.full((cout,), 0.25, jnp.float32)
+    return div, bias, ratio
+
+
+@pytest.mark.parametrize("accum", ["dot", "popcount"])
+@pytest.mark.parametrize("stride,with_skip", [(2, False), (1, True),
+                                              (2, True)],
+                         ids=["stride2", "shortcut", "stride2-shortcut"])
+def test_w1a8_conv_gemm_bit_exact_vs_ref(stride, with_skip, accum):
+    """The im2col route of the 3×3 conv: stride 2 computed at the output
+    pixels only (darknet's one pixel of padding a side), and the residual
+    input added after the ReLU in the epilogue, against the oracle."""
+    b, h, w, cin, cout = 2, 10, 10, 12, 40
+    kw, ka, ks = jax.random.split(jax.random.PRNGKey(stride * 10 + cin), 3)
+    wp = conv_ops.conv_pack_weights(jax.random.normal(kw, (3, 3, cin, cout)))
+    a = jax.random.randint(ka, (b, h, w, cin), 0, 256,
+                           jnp.int32).astype(jnp.uint8)
+    ho = (h - 1) // stride + 1
+    div, bias, ratio = _exact_epilogue_case(cout, stride)
+    res = {}
+    if with_skip:
+        res = {"skip": jax.random.randint(ks, (b, ho, ho, cout), 0, 256,
+                                          jnp.int32).astype(jnp.uint8),
+               "skip_ratio": ratio}
+    mul = jnp.ones((cin,), jnp.float32)
+    q_ref = conv_ref.w1a8_conv3x3_ref(a, wp, cin, mul, div, bias,
+                                      jnp.float32(1.0), stride=stride, **res)
+    cfg = KernelConfig(op="matmul", accum=accum, out_step=1.0,
+                       interpret=True)
+    q = conv_ops.w1a8_conv3x3_gemm(a, wp, mul, div, bias, cin=cin,
+                                   stride=stride, config=cfg, **res)
+    assert q.dtype == jnp.uint8 and q.shape == (b, ho, ho, cout)
+    q, q_ref = np.asarray(q), np.asarray(q_ref)
+    assert 0.05 < np.mean((q > 0) & (q < 255)), "codes must not all clip"
+    assert np.array_equal(q, q_ref)
+    # a stride-2 conv is the stride-1 conv at every other pixel
+    if stride == 2 and not with_skip:
+        full = conv_ref.w1a8_conv3x3_ref(a, wp, cin, mul, div, bias,
+                                         jnp.float32(1.0))
+        assert np.array_equal(q, np.asarray(full)[:, ::2, ::2])
+
+
+@pytest.mark.parametrize("accum", ["dot", "popcount"])
+def test_w1a8_matmul_shortcut_epilogue(accum):
+    """The residual epilogue adds the shortcut after the ReLU (darknet's
+    order): y = max(acc·div + bias, 0) + skip·ratio, then round/clip."""
+    m, k, n = 48, 96, 136
+    a, wp, *_ = _mm_case(m, k, n, seed=5)
+    div, bias, ratio = _exact_epilogue_case(n, 5)
+    skip = jax.random.randint(jax.random.PRNGKey(6), (m, n), 0, 256,
+                              jnp.int32).astype(jnp.uint8)
+    mul = jnp.ones((k,), jnp.float32)
+    cfg = KernelConfig(op="matmul", accum=accum, out_step=1.0,
+                       interpret=True)
+    q = np.asarray(mm_ops.w1a8_matmul(a, wp, mul, div, bias, k=k,
+                                      config=cfg, skip=skip,
+                                      skip_ratio=ratio))
+    y = np.asarray(mm_ref.w1a8_matmul_ref(a, wp, k, mul, div, bias),
+                   np.float64)
+    want = np.clip(np.trunc(np.maximum(y, 0) + np.asarray(skip) * 0.25
+                            + 0.5), 0, 255)
+    assert (y < 0).mean() > 0.1, "some convs must be cut by the ReLU"
+    assert np.array_equal(q, want)
+    with pytest.raises(ValueError, match="quantizing epilogue"):
+        mm_ops.w1a8_matmul(a, wp, mul, div, bias, k=k,
+                           config=cfg.replace(out_step=None), skip=skip,
+                           skip_ratio=ratio)

@@ -137,3 +137,51 @@ def test_serve_bundle_names_kernels_and_layers(serve_bundle):
         assert f'op_name="jit(_bundle)/{scope}/' in text \
             or f'op_name="jit(_bundle)/jit(postprocess)/{scope}/' in text, \
             scope
+
+
+def _yolov3_cells():
+    """W1A8 YOLOv3-416's distinct kernel shapes at real widths: the
+    stride-2 convs out of 416 and 26, the residual 3×3 convs at 208 and
+    13 (shortcut in the epilogue), a 1×1 at 13 and the routed 1×1 at 26."""
+    from repro.configs import yolov3_w1a8
+    g = yolov3_w1a8.GRAPH
+    sides = yolo.node_sides(g, 416)
+    keep = ("conv2", "conv4", "conv44", "conv52", "conv53", "conv61")
+    specs = {n.name: n for n in g.convs}
+    return [(name, specs[name], sides[name][0],
+             yolo._fused_shortcut(g, g.nodes.index(specs[name])) is not None)
+            for name in keep]
+
+
+@pytest.mark.parametrize("accum", ["dot", "popcount"])
+@pytest.mark.parametrize("name,spec,h,skip", _yolov3_cells(),
+                         ids=[c[0] for c in _yolov3_cells()])
+def test_yolov3_kernel_compiles_for_v5e(name, spec, h, skip, accum,
+                                        one_chip, no_persistent_cache):
+    op, dims = yolo._op_dims(spec, h, BATCH, skip)
+    assert op == "matmul"
+    cfg = KernelConfig(op=op, accum=accum, interpret=False, out_step=1.0)
+    ho = h // spec.stride
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    k = spec.ksize ** 2 * spec.cin
+    args = [sds((BATCH, h, h, spec.cin), jnp.uint8),
+            sds(((k + 31) // 32, spec.cout), jnp.uint32),
+            sds((spec.cin,), jnp.float32), sds((spec.cout,), jnp.float32),
+            sds((spec.cout,), jnp.float32)]
+    if skip:
+        args += [sds((BATCH, ho, ho, spec.cout), jnp.uint8),
+                 sds((spec.cout,), jnp.float32)]
+
+    def fn(a, wp, mul, div, b, *res):
+        kw = dict(zip(("skip", "skip_ratio"), res))
+        if spec.ksize == 3:
+            return conv_ops.w1a8_conv3x3_gemm(a, wp, mul, div, b,
+                                              cin=spec.cin,
+                                              stride=spec.stride,
+                                              config=cfg, **kw)
+        return mm_ops.w1a8_matmul(a, wp, mul, div, b, k=spec.cin,
+                                  config=cfg, **kw)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _custom_calls(compiled) == 1, (name, accum)
