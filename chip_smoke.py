@@ -8,10 +8,12 @@ One chip (the default): `build_detector` at 320x320x3 with seeded random
 weights, a `DetectionBackend` with compiled Pallas kernels (32-wide
 batches, 2-deep dispatch window, device NMS) plus a raw-head twin, and
 `Scheduler.run` serving 80 seeded uint8 images (two full batches and a
-partial one). It checks that every request completes in dispatch order,
-that the compiled bundle holds a Pallas kernel (``tpu_custom_call``) for
-each of the 9 W1A8 layers, and that the served raw head matches
-`yolo_forward_float` within one 0.02 LSB everywhere.
+partial one). It checks that conv1's pooled route gives its int64
+reference's accumulators and codes bit for bit on a seeded batch, that
+every request completes in dispatch order, that the compiled bundle holds
+a Pallas kernel (``tpu_custom_call``) for each of the 9 W1A8 layers, and
+that the served raw head matches `yolo_forward_float` within one 0.02 LSB
+everywhere.
 
 Four chips (``--chips 4``): the EP+TP sharded train step on a (2 data x 2
 model) mesh and the 1F1B pipelined steps on (2 stage x 2 data), each
@@ -96,6 +98,8 @@ def serve_phase(*, size: int = 320, slots: int = 32, n_requests: int = 80,
             f"{_launch(cfg, entry['spec'], sizes[name], slots)} "
             f"interpret={cfg.resolved_interpret()} source={cfg.source}")
 
+    conv1_off = conv1_phase(art, images[:slots])
+
     t0 = time.perf_counter()
     backend.warmup()
     twin.warmup()
@@ -145,7 +149,30 @@ def serve_phase(*, size: int = 320, slots: int = 32, n_requests: int = 80,
           f"served raw head off the float reference: {rep.row()}")
     return {"custom_calls": custom_calls, "served": served,
             "dropped": dropped, "compile_s": compile_s, "wall_s": wall_s,
-            "alignment": rep}
+            "alignment": rep, "conv1_off": conv1_off}
+
+
+def conv1_phase(art: dict, frames) -> tuple:
+    """conv1's pooled route on uint8 frames, compiled as the bundle
+    compiles it (the weights and step as constants), against its int64
+    reference `yolo.conv1_pooled_int`: returns how many pooled
+    accumulators and codes differ, and raises unless none does."""
+    import jax
+    import numpy as np
+    from repro.models import yolo
+    c1 = art["layers"][0]
+    w, b, step = c1["w"], c1["b"], c1["step_out"]
+    acc = np.asarray(jax.jit(lambda x: yolo._conv1_pool_blocks(x, w))(frames))
+    codes = np.asarray(jax.jit(
+        lambda x: yolo._conv1_pooled_exact(x, w, b, step).data)(frames))
+    want_acc, want = yolo.conv1_pooled_int(frames, w, b, step)
+    off = (int(np.sum(acc.reshape(want_acc.shape) != want_acc)),
+           int(np.sum(codes != want)))
+    log(f"conv1 pooled route vs its int64 reference: {off[0]} pooled "
+        f"accumulators and {off[1]} codes of {want.size} differ")
+    check(off == (0, 0), f"conv1's pooled route is off its int64 reference "
+                         f"(accumulators, codes): {off}")
+    return off
 
 
 def _placement(tree) -> tuple:

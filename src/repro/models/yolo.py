@@ -26,6 +26,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +42,7 @@ from repro.kernels.w1a8_conv import ops as conv_ops
 from repro.kernels.w1a8_matmul import ops as mm_ops
 
 PROFILES = ("tuned", "default", "interpret")
+_LANES = 128                  # lanes of a TPU vector register
 
 
 @dataclasses.dataclass(frozen=True)
@@ -559,6 +561,26 @@ def yolo_forward_int(art: dict, images_u8: np.ndarray) -> np.ndarray:
     raise AssertionError("unreachable")
 
 
+def conv1_pooled_int(images_u8: np.ndarray, w, b, step) -> tuple:
+    """The served conv1 on uint8 pixels (3×3 conv, ReLU, 2×2 max pool,
+    codes on ``step``) with its integers in int64: the pooled accumulator
+    max Σ a·n (pixel = a/256, Q5.11 weight = n/2048), then, in float32 as
+    the kernel path does, x = acc·2^-19 + b (Q2.14), ReLU and
+    ``clip(round(x / step), 0, 255)``. Returns (acc int64, codes uint8),
+    each (B, S/2, S/2, cout)."""
+    n = np.asarray(fxp.CONV1_W.quantize(jnp.asarray(w)), np.int64)
+    acc = _im2col_np(np.asarray(images_u8, np.int64), 3) \
+        @ n.reshape(-1, n.shape[-1])
+    bsz, h, wd, c = acc.shape
+    acc = acc.reshape(bsz, h // 2, 2, wd // 2, 2, c).max(axis=(2, 4))
+    frac = fxp.INPUT_Q.frac_bits + fxp.CONV1_W.frac_bits
+    x = acc.astype(np.float32) * np.float32(2.0 ** -frac) \
+        + np.asarray(fxp.CONV1_B.roundtrip(jnp.asarray(b)), np.float32)
+    v = np.maximum(x, np.float32(0)) / np.asarray(step, np.float32)
+    q = np.trunc(v + np.where(v >= 0, np.float32(0.5), np.float32(-0.5)))
+    return acc, np.clip(q, 0, ACT_QMAX).astype(np.uint8)
+
+
 # ---------------------------------------------------------------------------
 # Pallas-kernel inference path (packed 1-bit weights, fused epilogues)
 # ---------------------------------------------------------------------------
@@ -796,8 +818,11 @@ def graph_forward_kernel(art: dict, images: jax.Array, *,
                          profile: str = None, interpret: bool = None,
                          fuse_pool: bool = None, accum: str = None) -> tuple:
     """Pallas streaming path over the artifact's graph: images (B,S,S,3)
-    in [0,1] → the raw output of each ``yolo`` head, in graph order, f32
-    (B, G, G, anchors·(5 + classes)). Every node runs under a
+    in [0,1], or uint8 pixels (value = px/256) → the raw output of each
+    ``yolo`` head, in graph order, f32 (B, G, G, anchors·(5 + classes)).
+    A pooled first conv reads uint8 pixels exactly at pooled resolution
+    (`_conv1_pooled_exact`); any other first conv, and float images, take
+    the f32 path. Every node runs under a
     ``jax.named_scope`` of its name, inside its stage scope where the
     graph has stages, so that a trace's ops map to nodes through their
     metadata; each W1A8 conv is a Pallas call named ``w1a8_<layer>``.
@@ -839,6 +864,13 @@ def _conv_node(graph: Graph, i: int, entry: dict, images, ins: list,
     """One conv node of `graph_forward_kernel`."""
     spec = graph.nodes[i]
     if _reads_images(graph, spec):
+        if images.dtype == jnp.uint8 and spec.pool and spec.ksize == 3 \
+                and spec.stride == 1:
+            with jax.named_scope("pooled_exact"):
+                return _conv1_pooled_exact(images, entry["w"], entry["b"],
+                                           s_next)
+        if images.dtype == jnp.uint8:       # pixels / 256 is exact
+            images = images.astype(jnp.float32) / 256.0
         # conv1 (std, fixed-point-rounded weights) in f32, then quantize
         # to codes
         w1 = fxp.CONV1_W.roundtrip(entry["w"])
@@ -856,6 +888,66 @@ def _conv_node(graph: Graph, i: int, entry: dict, images, ins: list,
     fused = _fused_shortcut(graph, i)
     skip = vals[fused.src[0]] if fused is not None else None
     return _w1a8_layer(spec, entry, ins[0], cfg, s_next, skip)
+
+
+def _conv1_pool_blocks(pixels: jax.Array, w: jax.Array) -> jax.Array:
+    """The 2×2 max pool of a 3×3, stride-1 conv (one pixel of padding a
+    side) over uint8 pixels with Q5.11 weights, as the exact integers
+    max Σ a·n (pixel = a/256, weight = n/2048), in lane blocks: int32
+    (B, S/2, S/(2q), q·cout), whose block j holds pooled columns qj to
+    qj + q - 1 — the order of a row-major (B, S/2, S/2, cout) array.
+
+    Exactness: n = 256·h + l with h in -256..255 and l in 0..255, so the
+    pixels, h and l are exact in bf16, each product is exact on the MXU
+    and every partial sum stays below 2^24: bf16 contractions with f32
+    accumulation give Σ a·h and Σ a·l exactly, in any order.
+
+    Layout: one contraction over the padded pixels, a 4 × (2q + 2) window
+    at stride (2, 2q), computes the four sub-positions (dy, dx) of q
+    neighbouring pool windows, each holding the 3×3 kernel at its offset.
+    Its output channels are eight blocks of q·cout, one per (sub-position,
+    half), so that joining the halves and the pool's max are elementwise
+    between whole blocks of a vector register's lanes."""
+    n = fxp.CONV1_W.quantize(w)                     # (3, 3, cin, cout) int32
+    cout = n.shape[3]
+    q = math.gcd(pixels.shape[2] // 2, max(1, _LANES // cout))
+    halves = jnp.stack([n >> 8, n & 255], axis=3)   # (3, 3, cin, 2, cout)
+    k = jnp.stack([jnp.stack([
+        jnp.pad(halves, ((dy, 1 - dy), (2 * j + dx, 2 * q - 1 - 2 * j - dx),
+                         (0, 0), (0, 0), (0, 0)))
+        for j in range(q)], axis=4) for dy in range(2) for dx in range(2)],
+        axis=3)                                # (4, 2q+2, cin, 4, 2, q, cout)
+    xp = jnp.pad(pixels, ((0, 0), (1, 1), (1, 1), (0, 0)))
+    out = jax.lax.conv_general_dilated(
+        xp.astype(jnp.bfloat16),
+        k.reshape(k.shape[:3] + (-1,)).astype(jnp.bfloat16), (2, 2 * q),
+        "VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        preferred_element_type=jnp.float32)
+    blk = q * cout
+
+    def part(i):
+        return out[..., i * blk:(i + 1) * blk].astype(jnp.int32)
+    return functools.reduce(jnp.maximum, [256 * part(2 * s) + part(2 * s + 1)
+                                          for s in range(4)])
+
+
+def _conv1_pooled_exact(pixels: jax.Array, w: jax.Array, b: jax.Array,
+                        s_next: jax.Array) -> QTensor:
+    """conv1 + ReLU + 2×2 max pool on uint8 pixels, requantized to codes
+    on ``s_next``, computed at pooled resolution: the pool's max commutes
+    with the bias and the ReLU (both monotone), so only the pooled integer
+    accumulator (`_conv1_pool_blocks`) is scaled by 2^-19, biased and
+    quantized, in its lane blocks, and no full-resolution map is written."""
+    acc = _conv1_pool_blocks(pixels, w)
+    cout = b.shape[0]
+    reps = acc.shape[-1] // cout
+    frac = fxp.INPUT_Q.frac_bits + fxp.CONV1_W.frac_bits
+    x = acc.astype(jnp.float32) * 2.0 ** -frac \
+        + jnp.tile(fxp.CONV1_B.roundtrip(b), reps)
+    step = jnp.tile(jnp.broadcast_to(s_next, (cout,)), reps)
+    codes = QTensor.quantize_u8(jax.nn.relu(x), step, axis=-1).data
+    return QTensor.from_codes(codes.reshape(acc.shape[:2] + (-1, cout)),
+                              s_next, axis=-1)
 
 
 def yolo_forward_kernel(art: dict, images: jax.Array, *,

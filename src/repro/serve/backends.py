@@ -326,8 +326,9 @@ class DetectionBackend:
     per image size, the way `spawn()` shares one executable across
     replicas. A dispatch stages its frames as one host stack and one
     host→device transfer, in uint8 when every frame is uint8 (the bundle
-    converts to float first thing) and else in float32 converted on the
-    host (``float_stages`` counts those); ``stage_bytes`` counts the bytes
+    computes conv1 on the pixels themselves) and else in float32
+    converted on the host (``float_stages`` counts those);
+    ``stage_bytes`` counts the bytes
     sent. Only the real frames cross; a partial batch zero-pads on the
     device so every tick reuses the same executable. ``depth=K`` keeps up
     to K dispatches in flight, harvested strictly in dispatch order (see
@@ -425,10 +426,9 @@ class DetectionBackend:
         self.tracer = tracing.NULL                # host spans, when set
 
         def _bundle(imgs):
-            # frames cross the wire as uint8; pixels / 256 is exact (a
-            # power-of-two scale), so the head matches host-side conversion
-            if imgs.dtype == jnp.uint8:
-                imgs = imgs.astype(jnp.float32) / 256.0
+            # frames cross the wire as uint8 and go in as they are: the
+            # forward computes a pooled conv1 on the pixels themselves and
+            # converts them (pixels / 256, exact) for any other first conv
             heads = yolo.graph_forward_kernel(art, imgs, profile=profile,
                                               **overrides)
             raw = heads[0] if len(heads) == 1 else heads
@@ -590,8 +590,7 @@ class DetectionBackend:
     @staticmethod
     def _host_batch(images, wire) -> np.ndarray:
         """The frames stacked on the host in the wire dtype: uint8 as they
-        are (the bundle converts them), else float32 with uint8 frames
-        converted as the bundle would."""
+        are, else float32 with uint8 frames converted to pixels / 256."""
         if wire is np.uint8:
             return np.stack(images)
         return np.stack([

@@ -33,6 +33,7 @@ def test_serve_phase_tiny_in_interpret_mode(smoke, monkeypatch):
     assert obs["custom_calls"] == 0
     assert obs["alignment"].max_abs < smoke.RAW_LSB
     assert obs["alignment"].within_1lsb == 1.0
+    assert obs["conv1_off"] == (0, 0)
 
 
 def test_four_chip_phase_on_virtual_devices(smoke):

@@ -1,8 +1,8 @@
 """Host staging and spans of the detector server (serve/tracing.py): a few
 width-4 batches at 64x64 through Scheduler over DetectionBackend, kernels
-in interpret mode. Staging sends uint8 frames as one transfer and converts
-them inside the bundle; the served results stay bit-identical to the
-bundle run on host-converted float32 frames."""
+in interpret mode. Staging sends uint8 frames as one transfer, and the
+bundle computes conv1 on the pixels themselves; the served results stay
+bit-identical to the bundle run on host-converted float32 frames."""
 import types
 
 import jax
@@ -159,9 +159,9 @@ def assert_bit_identical(got, ref, rows):
 
 @pytest.mark.parametrize("n", [WIDTH, 2])
 def test_uint8_frames_serve_bit_identical_to_host_conversion(art, n):
-    """A full and a partial batch of uint8 frames: converted inside the
-    bundle, the head and detections equal the float32 bundle's bit for
-    bit."""
+    """A full and a partial batch of uint8 frames: through conv1's exact
+    pooled route, the head and detections equal the float32 bundle's (the
+    f32 path) bit for bit."""
     rng = np.random.default_rng(5)
     frames = list(rng.integers(0, 256, (n, SIZE, SIZE, 3), np.uint8))
     backend, got = serve_raw(art, frames)

@@ -103,6 +103,10 @@ def serve_bundle(topo, one_chip, no_persistent_cache):
     320 x 32 slots, as `chip_smoke.py` serves it, compiled for one chip.
     The configs are steered to what a v5e resolves: the chip's autotune
     key, and compiled (not interpreted) kernels."""
+    return _compile_serve_bundle(topo, one_chip, 320)
+
+
+def _compile_serve_bundle(topo, one_chip, size: int):
     from repro.serve import DetectionBackend
     kind = topo.devices[0].device_kind
     with pytest.MonkeyPatch.context() as mp:
@@ -111,12 +115,12 @@ def serve_bundle(topo, one_chip, no_persistent_cache):
         mp.setattr(KernelConfig, "resolved_interpret",
                    lambda self: bool(self.interpret))
         rng = np.random.default_rng(0)
-        calib = jnp.asarray(rng.integers(0, 256, (1, 320, 320, 3), np.uint8),
-                            jnp.float32) / 256
+        calib = jnp.asarray(rng.integers(0, 256, (1, size, size, 3),
+                                         np.uint8), jnp.float32) / 256
         _, art = yolo.build_detector(jax.random.PRNGKey(0), calib)
         backend = DetectionBackend(art, slots=SERVE_SLOTS, depth=2,
                                    profile="tuned", device_nms=True)
-        return backend.lower(320, sharding=one_chip).compile()
+        return backend.lower(size, sharding=one_chip).compile()
 
 
 def test_serve_bundle_compiles_for_v5e(serve_bundle):
@@ -137,6 +141,28 @@ def test_serve_bundle_names_kernels_and_layers(serve_bundle):
         assert f'op_name="jit(_bundle)/{scope}/' in text \
             or f'op_name="jit(_bundle)/jit(postprocess)/{scope}/' in text, \
             scope
+
+
+@pytest.fixture(scope="module")
+def serve_bundle_608(topo, one_chip, no_persistent_cache):
+    """The same bundle at 608 x 32 slots."""
+    return _compile_serve_bundle(topo, one_chip, 608)
+
+
+@pytest.mark.parametrize("size", [320, 608])
+def test_serve_bundle_pools_conv1_without_a_full_map(size, request):
+    """uint8 frames go through conv1's pooled route (scope
+    ``conv1/pooled_exact``): no array holds the full-resolution conv1 map
+    (B x S x S x 16, of any dtype), no window reduction (the 2 x 2 pool) is
+    left under conv1, and the nine W1A8 kernels are all still there."""
+    compiled = request.getfixturevalue(
+        "serve_bundle" if size == 320 else "serve_bundle_608")
+    text = compiled.as_text()
+    assert 'op_name="jit(_bundle)/conv1/pooled_exact/' in text
+    assert f"[{SERVE_SLOTS},{size},{size},16]" not in text
+    assert not [line for line in text.splitlines()
+                if "reduce-window(" in line and "/conv1/" in line]
+    assert _custom_calls(compiled) == N_W1A8_LAYERS
 
 
 def _yolov3_cells():
